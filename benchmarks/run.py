@@ -498,16 +498,16 @@ def bench_kernels_batched():
 
     def per_stripe_xor():
         for s in range(s_count):
-            ops.xor_parity(data[s]).block_until_ready()
+            ops.xor_parity(data[s], use_pallas=True).block_until_ready()
 
     def per_stripe_rs():
         for s in range(s_count):
-            ops.rs_encode(data[s], 2).block_until_ready()
+            ops.rs_encode(data[s], 2, use_pallas=True).block_until_ready()
 
-    us_b = _timeit(lambda: ops.xor_parity_batch(data).block_until_ready())
+    us_b = _timeit(lambda: ops.xor_parity_batch(data, use_pallas=True).block_until_ready())
     us_l = _timeit(per_stripe_xor)
     emit(f"kernels/parity_xor_batch_S{s_count}", us_b, f"{us_l / us_b:.1f}x_vs_loop")
-    us_b = _timeit(lambda: ops.rs_encode_batch(data, 2).block_until_ready())
+    us_b = _timeit(lambda: ops.rs_encode_batch(data, 2, use_pallas=True).block_until_ready())
     us_l = _timeit(per_stripe_rs)
     emit(f"kernels/rs_encode_batch_S{s_count}", us_b, f"{us_l / us_b:.1f}x_vs_loop")
 
@@ -667,16 +667,17 @@ def bench_kernels():
 
     rng = np.random.default_rng(6)
     data = jnp.asarray(rng.integers(0, 2**31, (3, 65536), dtype=np.int64), jnp.int32)
-    us = _timeit(lambda: ops.xor_parity(data).block_until_ready())
+    us = _timeit(lambda: ops.xor_parity(data, use_pallas=True).block_until_ready())
     emit("kernels/parity_xor_256KiB", us, f"{3*65536*4/1e3:.0f}KB_in")
-    us = _timeit(lambda: ops.rs_encode(data, 2).block_until_ready())
+    us = _timeit(lambda: ops.rs_encode(data, 2, use_pallas=True).block_until_ready())
     emit("kernels/rs_encode_m2_256KiB", us, "gf256_swar")
     x = jnp.asarray(rng.standard_normal((4, 512, 64)), jnp.float32)
     dt = jnp.asarray(rng.uniform(0.01, 0.1, (4, 512)), jnp.float32)
     a = jnp.asarray(-rng.uniform(0.5, 2, (4,)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((4, 512, 32)), jnp.float32)
     c = jnp.asarray(rng.standard_normal((4, 512, 32)), jnp.float32)
-    us = _timeit(lambda: ops.ssd_chunk_scan(x, dt, a, b, c, chunk=128)[0].block_until_ready())
+    us = _timeit(lambda: ops.ssd_chunk_scan(
+        x, dt, a, b, c, chunk=128, use_pallas=True)[0].block_until_ready())
     emit("kernels/ssd_scan_4x512", us, "pallas_interpret")
 
 

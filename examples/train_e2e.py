@@ -16,7 +16,7 @@ sys.argv = [sys.argv[0]] + (
      "--fail-lane", "2", "--fail-at", "8", "--restart-at", "14",
      "--global-batch", "8", "--seq-len", "64"]
     if "--full" not in sys.argv
-    else ["--arch", "smollm-135m", "--steps", "200", "--ckpt-every", "25",
+    else ["--arch", "smollm-135m", "--no-smoke", "--steps", "200", "--ckpt-every", "25",
           "--global-batch", "32", "--seq-len", "2048"]
 )
 if "--full" in sys.argv:

@@ -12,6 +12,8 @@ All functions operate on byte-views of pytree leaves, so any dtype works.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,9 +33,13 @@ def _lanes_to_leaf(lanes: jnp.ndarray, dtype, shape, nbytes: int) -> np.ndarray:
     return np.frombuffer(raw, dtype=dtype).reshape(shape).copy()
 
 
-def encode_shards(shards: list, m: int = 1, *, use_pallas: bool = True,
-                  interpret: bool = True) -> list:
-    """Compute m parity pytrees over k rank-shard pytrees (leafwise)."""
+def encode_shards(shards: list, m: int = 1, *,
+                  use_pallas: Optional[bool] = None,
+                  interpret: Optional[bool] = None) -> list:
+    """Compute m parity pytrees over k rank-shard pytrees (leafwise).
+
+    The codec mode follows the backend unless given (see
+    :mod:`repro.kernels.backend`)."""
     k = len(shards)
     flat = [jax.tree.leaves(s) for s in shards]
     treedef = jax.tree.structure(shards[0])
@@ -60,8 +66,8 @@ def reconstruct_shard(
     parity: list,
     k: int,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ):
     """Rebuild rank ``lost_rank``'s shard pytree from k-1 survivors + parity."""
     m = len(parity)

@@ -31,7 +31,9 @@ import jax
 import numpy as np
 
 from repro.core.array import ZapRaidConfig, ZapRAIDArray
+from repro.core.raid import make_scheme
 from repro.core.recovery import recover_array
+from repro.core.segment import solve_stripes_per_segment
 from repro.core.zns import ZnsConfig
 
 MANIFEST_LBAS = 64  # reserved logical region for the manifest
@@ -47,12 +49,11 @@ class CheckpointConfig:
     zone_cap_blocks: int = 4096
     n_zones: int = 64
     keep_last: int = 2
-    # datapath: the jnp oracle (use_pallas=False) is the fast path on CPU
-    # (jitted XLA); interpret-mode Pallas is for kernel validation and runs
-    # the kernel body in Python -- orders of magnitude slower for bulk
-    # rebuild loops.  On real TPUs set use_pallas=True, interpret=False.
-    use_pallas: bool = False
-    interpret: bool = True
+    # datapath: None follows the backend -- compiled Pallas kernels on a
+    # TPU, the jitted jnp oracle elsewhere (interpret-mode Pallas runs the
+    # kernel body in Python and is for kernel validation only)
+    use_pallas: Optional[bool] = None
+    interpret: Optional[bool] = None
 
     def zap_cfg(self, logical_blocks: int) -> ZapRaidConfig:
         return ZapRaidConfig(
@@ -72,6 +73,17 @@ class CheckpointConfig:
             zone_cap_blocks=self.zone_cap_blocks,
             block_bytes=self.block_bytes,
         )
+
+
+def state_blocks(state, block_bytes: int) -> int:
+    """Blocks one checkpoint of ``state`` occupies (whole blocks per leaf,
+    as ``CheckpointEngine._stage_save`` lays them out).  Reads only shapes
+    and dtypes, so device arrays stay where they are."""
+    return sum(
+        max(1, -(-math.prod(leaf.shape) * np.dtype(leaf.dtype).itemsize
+                 // block_bytes))
+        for leaf in jax.tree.leaves(state)
+    )
 
 
 def _flatten_state(state) -> tuple[list[tuple[str, np.ndarray]], Any]:
@@ -142,6 +154,27 @@ class CheckpointEngine:
         self.catalog: dict[int, dict] = {}  # step -> manifest
         self._alloc_ptr = lba_base + MANIFEST_LBAS  # bump allocator, ring
         self.saves = 0
+
+    @classmethod
+    def for_state(cls, state, cfg: Optional[CheckpointConfig] = None):
+        """An engine whose geometry is sized from the bytes of ``state``.
+
+        The logical ring holds ``keep_last + 1`` checkpoints, so a save in
+        progress never overwrites one the catalog still keeps.  Each lane
+        gets zones for its share of the ring plus a quarter more for GC
+        headroom, and four spare zones (the open segment and the GC
+        watermark).  ``cfg`` supplies everything but ``n_zones``."""
+        cfg = cfg or CheckpointConfig()
+        logical = MANIFEST_LBAS + (cfg.keep_last + 1) * state_blocks(
+            state, cfg.block_bytes
+        )
+        k = make_scheme(cfg.scheme, cfg.n_lanes).k
+        stripes, _ = solve_stripes_per_segment(
+            cfg.zone_cap_blocks, cfg.chunk_blocks, cfg.block_bytes
+        )
+        segment_data = k * stripes * cfg.chunk_blocks  # data blocks per segment
+        n_zones = -(-(logical * 5 // 4) // segment_data) + 4
+        return cls(dataclasses.replace(cfg, n_zones=n_zones), logical)
 
     @classmethod
     def build_timed(

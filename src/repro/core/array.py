@@ -110,9 +110,10 @@ class ZapRaidConfig:
     # *store* is always maintained at commit time, only the read-side verify
     # pass is optional (bit-identity with pre-integrity baselines).
     verify_reads: bool = False
-    # datapath
-    use_pallas: bool = False
-    interpret: bool = True
+    # datapath: codec mode; None follows the backend (compiled Pallas
+    # kernels on a TPU, the jnp reference elsewhere -- repro.kernels.backend)
+    use_pallas: Optional[bool] = None
+    interpret: Optional[bool] = None
     batched: bool = True           # group-level fused encode + vectorized I/O
     # double-buffered group commits: the fused encode for group g+1 is
     # dispatched (JAX async, donated buffers) before group g's chunks are

@@ -13,12 +13,14 @@ paper sketches in Figure 3.
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import gf
 from repro.kernels import ops
+from repro.kernels.backend import codec_mode
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,12 +88,21 @@ class StripeCodec:
     ``copy_stats`` (optional) is an object with ``h2d_copies/h2d_bytes/
     d2h_copies/d2h_bytes`` counters (e.g. :class:`repro.core.array.Stats`)
     bumped on every host<->device transfer the codec performs.
+
+    The codec mode is resolved once, here, from the backend when left unset
+    (:mod:`repro.kernels.backend`): compiled Pallas kernels on a TPU, the
+    jnp reference elsewhere.
     """
 
-    def __init__(self, scheme: RaidScheme, *, use_pallas: bool = False, interpret: bool = True):
+    def __init__(
+        self,
+        scheme: RaidScheme,
+        *,
+        use_pallas: Optional[bool] = None,
+        interpret: Optional[bool] = None,
+    ):
         self.scheme = scheme
-        self.use_pallas = use_pallas
-        self.interpret = interpret
+        self.use_pallas, self.interpret = codec_mode(use_pallas, interpret)
         self.copy_stats = None
 
     # -- host<->device accounting -------------------------------------------

@@ -17,12 +17,14 @@ so the kernel stays bandwidth-bound like the XOR kernel (within ~1.3x).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.gf import swar_gf_scale
+from repro.kernels.backend import lane_block, resolve_interpret
 
 DEFAULT_BLOCK_N = 2048
 
@@ -59,20 +61,20 @@ def gf256_matmul_batch(
     data: jax.Array,
     *,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(m, k) GF coeffs x (S, k, n) packed int32 -> (S, m, n) packed int32.
 
     Batched variant for whole stripe groups: a 2-D (stripe, lane-tile) grid
     runs the same SWAR double-and-add body per tile, with the tiny coefficient
     matrix broadcast to every grid step, so one ``pallas_call`` encodes (or
-    decodes) all S stripes instead of S dispatches.
+    decodes) all S stripes instead of S dispatches.  ``interpret`` follows
+    the backend (see :mod:`repro.kernels.backend`).
     """
     m, k = coeff.shape
     s, k2, n = data.shape
     assert k == k2, (coeff.shape, data.shape)
-    bn = min(block_n, n)
-    assert n % bn == 0 and bn % 128 == 0, (n, bn)
+    bn = lane_block(n, block_n)
     return pl.pallas_call(
         _make_batch_kernel(m, k),
         grid=(s, n // bn),
@@ -82,7 +84,7 @@ def gf256_matmul_batch(
         ],
         out_specs=pl.BlockSpec((1, m, bn), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((s, m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(coeff.astype(jnp.int32), data)
 
 
@@ -92,14 +94,13 @@ def gf256_matmul(
     data: jax.Array,
     *,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(m, k) GF coeffs x (k, n) packed int32 -> (m, n) packed int32."""
     m, k = coeff.shape
     k2, n = data.shape
     assert k == k2, (coeff.shape, data.shape)
-    bn = min(block_n, n)
-    assert n % bn == 0 and bn % 128 == 0, (n, bn)
+    bn = lane_block(n, block_n)
     return pl.pallas_call(
         _make_kernel(m, k),
         grid=(n // bn,),
@@ -109,5 +110,5 @@ def gf256_matmul(
         ],
         out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(coeff.astype(jnp.int32), data)

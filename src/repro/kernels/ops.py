@@ -1,20 +1,22 @@
 """Public jit'd entry points for the kernels package.
 
-Every op takes ``use_pallas``/``interpret`` switches so the same call site
-serves three modes:
+Every codec op takes ``use_pallas``/``interpret``; left unset, both follow
+the backend (:mod:`repro.kernels.backend`), resolved once here:
 
-* ``use_pallas=False``   -> pure-jnp oracle (CPU datapath, autodiff-safe)
-* ``use_pallas=True, interpret=True``  -> Pallas kernel body on CPU (tests)
-* ``use_pallas=True, interpret=False`` -> compiled TPU kernel (production)
+* on a TPU -> the compiled Pallas kernel (``interpret=False``);
+* elsewhere -> the pure-jnp oracle (``use_pallas=False``; CPU datapath,
+  autodiff-safe), or with an explicit ``use_pallas=True`` the Pallas kernel
+  body in interpret mode (tests).
 
-Byte-level helpers convert between uint8 chunk buffers and the int32-packed
-lanes the kernels consume.
+An explicit ``interpret=True`` on a TPU raises.  Byte-level helpers convert
+between uint8 chunk buffers and the int32-packed lanes the kernels consume.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
 import warnings
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ import numpy as np
 
 from repro.core import gf
 from repro.kernels import ref
+from repro.kernels.backend import LANES, codec_mode
 from repro.kernels.gf256_matmul import gf256_matmul, gf256_matmul_batch
 from repro.kernels.parity_xor import parity_xor, parity_xor_batch
 from repro.kernels.ssd_scan import ssd_scan
@@ -59,9 +62,9 @@ def unpack_bytes(data_i32: jax.Array) -> jax.Array:
 
 
 def _pad_lanes(x: jax.Array) -> tuple[jax.Array, int]:
-    """Pad the lane dim up to a multiple of 128 (TPU lane width)."""
+    """Pad the lane dim up to a multiple of the TPU lane width."""
     n = x.shape[-1]
-    pad = (-n) % 128
+    pad = (-n) % LANES
     if pad:
         x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
     return x, n
@@ -69,9 +72,13 @@ def _pad_lanes(x: jax.Array) -> tuple[jax.Array, int]:
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def xor_parity(
-    chunks_i32: jax.Array, *, use_pallas: bool = True, interpret: bool = True
+    chunks_i32: jax.Array,
+    *,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """XOR parity of (k, n) int32 -> (n,) int32."""
+    use_pallas, interpret = codec_mode(use_pallas, interpret)
     if use_pallas:
         padded, n = _pad_lanes(chunks_i32)
         return parity_xor(padded, interpret=interpret)[:n]
@@ -83,10 +90,11 @@ def rs_matmul(
     coeff_i32: jax.Array,
     chunks_i32: jax.Array,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """GF(256) (m,k) x (k,n) -> (m,n) on int32-packed bytes."""
+    use_pallas, interpret = codec_mode(use_pallas, interpret)
     if use_pallas:
         padded, n = _pad_lanes(chunks_i32)
         return gf256_matmul(coeff_i32, padded, interpret=interpret)[:, :n]
@@ -97,8 +105,8 @@ def rs_encode(
     chunks_i32: jax.Array,
     m: int,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Encode (k, n) data chunks into (m, n) RS parity chunks."""
     k = chunks_i32.shape[0]
@@ -112,8 +120,8 @@ def rs_decode(
     k: int,
     m: int,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Reconstruct the k data chunks from any k surviving codeword rows."""
     dec = rs_decode_coeff(k, m, tuple(surviving_rows))
@@ -124,9 +132,13 @@ def rs_decode(
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def xor_parity_batch(
-    chunks_i32: jax.Array, *, use_pallas: bool = True, interpret: bool = True
+    chunks_i32: jax.Array,
+    *,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """XOR parity for a whole stripe group: (S, k, n) int32 -> (S, n) int32."""
+    use_pallas, interpret = codec_mode(use_pallas, interpret)
     if use_pallas:
         padded, n = _pad_lanes(chunks_i32)
         return parity_xor_batch(padded, interpret=interpret)[:, :n]
@@ -138,10 +150,11 @@ def rs_matmul_batch(
     coeff_i32: jax.Array,
     chunks_i32: jax.Array,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """GF(256) (m,k) x (S,k,n) -> (S,m,n) on int32-packed bytes."""
+    use_pallas, interpret = codec_mode(use_pallas, interpret)
     if use_pallas:
         padded, n = _pad_lanes(chunks_i32)
         return gf256_matmul_batch(coeff_i32, padded, interpret=interpret)[:, :, :n]
@@ -152,8 +165,8 @@ def rs_encode_batch(
     chunks_i32: jax.Array,
     m: int,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Encode (S, k, n) stripes into (S, m, n) RS parity in one fused call."""
     k = chunks_i32.shape[1]
@@ -169,8 +182,8 @@ def rs_decode_batch(
     k: int,
     m: int,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Reconstruct (S, k, n) data from (S, k, n) survivors sharing one role set."""
     dec = rs_decode_coeff(k, m, tuple(surviving_rows))
@@ -208,9 +221,13 @@ def quiet_donation():
     jax.jit, static_argnames=("use_pallas", "interpret"), donate_argnums=(0,)
 )
 def xor_parity_batch_device(
-    chunks_i32: jax.Array, *, use_pallas: bool = True, interpret: bool = True
+    chunks_i32: jax.Array,
+    *,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Donating ``xor_parity_batch``: (S, k, n) int32 -> (S, n) int32."""
+    use_pallas, interpret = codec_mode(use_pallas, interpret)
     if use_pallas:
         padded, n = _pad_lanes(chunks_i32)
         return parity_xor_batch(padded, interpret=interpret)[:, :n]
@@ -224,10 +241,11 @@ def rs_matmul_batch_device(
     coeff_i32: jax.Array,
     chunks_i32: jax.Array,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Donating ``rs_matmul_batch``: coeff kept, stripe buffer donated."""
+    use_pallas, interpret = codec_mode(use_pallas, interpret)
     if use_pallas:
         padded, n = _pad_lanes(chunks_i32)
         return gf256_matmul_batch(coeff_i32, padded, interpret=interpret)[:, :, :n]
@@ -238,8 +256,8 @@ def rs_encode_batch_device(
     chunks_i32: jax.Array,
     m: int,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Donating ``rs_encode_batch`` (cached coeff matrix, donated stripes)."""
     k = chunks_i32.shape[1]
@@ -255,8 +273,8 @@ def rs_decode_batch_device(
     k: int,
     m: int,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    use_pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Donating ``rs_decode_batch`` (cached decode matrix, donated survivors)."""
     dec = rs_decode_coeff(k, m, tuple(surviving_rows))
@@ -283,9 +301,10 @@ def unpack_bytes_np(data_i32: np.ndarray) -> np.ndarray:
 
 def ssd_chunk_scan(
     x, dt, a, b, c, h0=None, *, chunk: int = 128,
-    use_pallas: bool = True, interpret: bool = True,
+    use_pallas: Optional[bool] = None, interpret: Optional[bool] = None,
 ):
     """Mamba-2 SSD scan; see kernels/ssd_scan.py.  Returns (y, h_final)."""
+    use_pallas, interpret = codec_mode(use_pallas, interpret)
     if use_pallas:
         return ssd_scan(x, dt, a, b, c, h0, chunk=chunk, interpret=interpret)
     return ref.ssd_scan_ref(x, dt, a, b, c, h0)

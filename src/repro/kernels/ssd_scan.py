@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 DEFAULT_CHUNK = 128
 
 
@@ -77,9 +79,11 @@ def ssd_scan(
     h0: jax.Array | None = None,  # (bh, n, p)
     *,
     chunk: int = DEFAULT_CHUNK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Blocked SSD scan; returns (y (bh,t,p) f32, h_final (bh,n,p) f32)."""
+    """Blocked SSD scan; returns (y (bh,t,p) f32, h_final (bh,n,p) f32).
+
+    ``interpret`` follows the backend (see :mod:`repro.kernels.backend`)."""
     bh, t, p = x.shape
     n = b.shape[-1]
     q = min(chunk, t)
@@ -107,6 +111,6 @@ def ssd_scan(
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, a[:, None], b, c, h0)
     return y, h_final

@@ -71,7 +71,9 @@ def run(argv=None):
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-len", type=int, default=24)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction, default=True,
+                    help="shrink the model to CPU-test size; --no-smoke runs "
+                         "the published widths")
     ap.add_argument("--storage-sim", action="store_true",
                     help="run the checkpoint-under-serving storage scenario")
     ap.add_argument("--policy", default="both", choices=("qos", "fifo", "both"))
@@ -128,4 +130,7 @@ def run(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     run()

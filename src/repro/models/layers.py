@@ -39,13 +39,8 @@ TP_AXES = {"heads", "kv", "ff", "vocab", "experts",
 
 
 def _ambient_mesh():
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or am.empty:
-            return None
-        return am
-    except Exception:  # pragma: no cover - older jax
-        return None
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def _wsc(x, parts):

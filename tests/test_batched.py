@@ -48,7 +48,8 @@ def test_parity_xor_batch_matches_per_stripe(s_count, n):
         rng.integers(-(2**31), 2**31, (s_count, 4, n), dtype=np.int64), jnp.int32
     )
     got = ops.xor_parity_batch(data, use_pallas=True, interpret=True)
-    per = jnp.stack([ops.xor_parity(data[s]) for s in range(s_count)])
+    per = jnp.stack([ops.xor_parity(data[s], use_pallas=True)
+                     for s in range(s_count)])
     assert jnp.array_equal(got, per)
     assert np.array_equal(
         np.asarray(got), np.bitwise_xor.reduce(np.asarray(data), axis=1)
@@ -66,7 +67,8 @@ def test_gf256_matmul_batch_matches_per_stripe(k, m):
         rng.integers(-(2**31), 2**31, (5, k, 512), dtype=np.int64), jnp.int32
     )
     got = ops.rs_encode_batch(data, m, use_pallas=True, interpret=True)
-    per = jnp.stack([ops.rs_encode(data[s], m) for s in range(5)])
+    per = jnp.stack([ops.rs_encode(data[s], m, use_pallas=True)
+                     for s in range(5)])
     assert jnp.array_equal(got, per)
     assert jnp.array_equal(ops.rs_encode_batch(data, m, use_pallas=False), got)
 
@@ -77,10 +79,11 @@ def test_rs_decode_batch_roundtrip():
     data = jnp.asarray(
         rng.integers(-(2**31), 2**31, (4, k, 256), dtype=np.int64), jnp.int32
     )
-    parity = ops.rs_encode_batch(data, m)
+    parity = ops.rs_encode_batch(data, m, use_pallas=True)
     code = jnp.concatenate([data, parity], axis=1)
     for surv in itertools.combinations(range(k + m), k):
-        rec = ops.rs_decode_batch(code[:, list(surv)], surv, k, m)
+        rec = ops.rs_decode_batch(code[:, list(surv)], surv, k, m,
+                                  use_pallas=True)
         assert jnp.array_equal(rec, data), surv
 
 

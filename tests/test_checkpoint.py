@@ -95,6 +95,32 @@ def test_log_structured_gc_under_many_saves():
     assert eng.array.stats.device_blocks_written > 0
 
 
+def test_engine_sized_from_state_bytes():
+    """``for_state`` sizes the ring for keep_last+1 saves of the state and
+    the zones for that plus GC headroom: many saves wrap the ring and GC
+    the stale extents, and a degraded restore of the last one is exact."""
+    from repro.checkpoint.zapraid_ckpt import MANIFEST_LBAS, state_blocks
+
+    def big_state(seed):
+        rng = np.random.default_rng(seed)
+        return {"w": jnp.asarray(rng.standard_normal((4, 64, 64)), jnp.float32),
+                "b": jnp.asarray(rng.standard_normal(100), jnp.bfloat16),
+                "step": jnp.int32(seed)}
+
+    cfg = CheckpointConfig(n_lanes=4, scheme="raid5", group_size=8,
+                           block_bytes=512, zone_cap_blocks=256, chunk_blocks=2)
+    st = big_state(0)
+    eng = CheckpointEngine.for_state(st, cfg)
+    per_save = state_blocks(st, cfg.block_bytes)
+    assert per_save == 128 + 1 + 1
+    assert eng.logical_blocks == MANIFEST_LBAS + 3 * per_save
+    for s in range(1, 25):
+        eng.save(s, big_state(s))
+    assert eng.array.stats.gc_runs > 0
+    eng.fail_lane(1)
+    assert trees_equal(big_state(24), eng.restore(24, st))
+
+
 # ------------------------------------------------------ state parity (EC)
 
 @pytest.mark.parametrize("m", [1, 2])
@@ -108,10 +134,10 @@ def test_optimizer_shard_reconstruction(m):
         }
         for _ in range(k)
     ]
-    parity = encode_shards(shards, m=m)
+    parity = encode_shards(shards, m=m, use_pallas=True)
     lost = 2
     surviving = {r: shards[r] for r in range(k) if r != lost}
-    rec = reconstruct_shard(lost, surviving, parity, k)
+    rec = reconstruct_shard(lost, surviving, parity, k, use_pallas=True)
     assert trees_equal(rec, shards[lost])
 
 

@@ -68,6 +68,45 @@ def test_parity_xor_shapes(k, n):
     )
 
 
+@pytest.mark.parametrize("k", range(1, 8))
+@pytest.mark.parametrize("n", [1024, 3072, 4096])
+def test_unrolled_xor_kernels_match_ref(k, n):
+    """The unrolled-XOR kernels, single-stripe and batched, are bit-identical
+    to the oracle; n=3072 (a 12 KiB chunk) takes a 1536-lane block, the
+    largest multiple of 128 that divides it within the 2048 default."""
+    from repro.kernels.backend import lane_block
+    from repro.kernels.parity_xor import parity_xor, parity_xor_batch
+
+    rng = np.random.default_rng(k * n)
+    x = jnp.asarray(
+        rng.integers(-(2**31), 2**31, (4, k, n), dtype=np.int64), jnp.int32
+    )
+    assert jnp.array_equal(parity_xor(x[0], interpret=True),
+                           ref.parity_xor_ref(x[0]))
+    assert jnp.array_equal(parity_xor_batch(x, interpret=True),
+                           ref.parity_xor_batch_ref(x))
+    bn = lane_block(n, 2048)
+    assert bn == {1024: 1024, 3072: 1536, 4096: 2048}[n]
+
+
+@pytest.mark.parametrize("tpu", [False, True])
+def test_codec_mode_follows_backend(monkeypatch, tpu):
+    """Unset, the codec runs compiled Pallas on a TPU and the jnp reference
+    elsewhere; an explicit interpret=True on a TPU is refused."""
+    from repro.core.raid import StripeCodec, make_scheme
+    from repro.kernels import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: tpu)
+    codec = StripeCodec(make_scheme("raid5", 4))
+    assert (codec.use_pallas, codec.interpret) == (tpu, not tpu)
+    assert backend.resolve_interpret(False) is False
+    if tpu:
+        with pytest.raises(ValueError, match="TPU"):
+            StripeCodec(make_scheme("raid5", 4), use_pallas=True, interpret=True)
+    else:
+        assert backend.resolve_interpret(True) is True
+
+
 def test_parity_xor_unaligned_lanes():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.integers(0, 2**31, (3, 20), dtype=np.int64), jnp.int32)
@@ -139,12 +178,13 @@ def test_ssd_scan_state_continuation():
     a = jnp.asarray(-rng.uniform(0.5, 2.0, (bh,)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((bh, t, n)), jnp.float32)
     c = jnp.asarray(rng.standard_normal((bh, t, n)), jnp.float32)
-    y_full, h_full = ops.ssd_chunk_scan(x, dt, a, b, c, chunk=32)
+    y_full, h_full = ops.ssd_chunk_scan(x, dt, a, b, c, chunk=32,
+                                        use_pallas=True)
     half = t // 2
     y1, h1 = ops.ssd_chunk_scan(x[:, :half], dt[:, :half], a, b[:, :half],
-                                c[:, :half], chunk=32)
+                                c[:, :half], chunk=32, use_pallas=True)
     y2, h2 = ops.ssd_chunk_scan(x[:, half:], dt[:, half:], a, b[:, half:],
-                                c[:, half:], h1, chunk=32)
+                                c[:, half:], h1, chunk=32, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y_full[:, half:]), np.asarray(y2),
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(h_full), np.asarray(h2),

@@ -16,9 +16,44 @@ def test_end_to_end_training_with_failure_and_restart(capsys):
         "--arch", "qwen2.5-3b", "--steps", "6", "--ckpt-every", "2",
         "--fail-lane", "1", "--fail-at", "3", "--restart-at", "4",
         "--global-batch", "4", "--seq-len", "32",
-    ])
+    ]).losses
     assert len(losses) >= 6
     assert all(np.isfinite(l) for l in losses)
+
+
+def test_no_smoke_parses_to_full_width():
+    """``--no-smoke`` reaches the published config; the default stays smoke
+    size.  Parsing only: no model is built."""
+    full = train_mod.model_config(train_mod.parse_args(["--no-smoke"]))
+    assert full == get_config("smollm-135m")
+    assert (full.n_layers, full.d_model, full.vocab, full.dtype) == (
+        30, 576, 49152, "bfloat16"
+    )
+    small = train_mod.model_config(train_mod.parse_args([]))
+    assert small == smoke(get_config("smollm-135m"))
+
+
+def test_compile_cache_dir_placed_from_outside(monkeypatch):
+    """The entry points' cache goes to $JAX_COMPILATION_CACHE_DIR where it is
+    set (and nothing else is chosen), else to the fixed <repo>/.jax_cache."""
+    import pathlib
+
+    from repro.launch import compile_cache
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert compile_cache.use_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[1])
 
 
 @pytest.mark.parametrize("arch", ARCHS)
