@@ -1,0 +1,9 @@
+"""Share of the HBM roofline of the single-stripe XOR program (jit_xor_parity) that a degraded 4 KiB read dispatches: bytes in plus out, over its device time."""
+LAYER = "kernels"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "read_MiBps"
+
+
+def read(w):
+    return w.roofline_pct("xor", ("xor_parity",))
