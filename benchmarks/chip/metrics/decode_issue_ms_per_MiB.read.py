@@ -1,0 +1,11 @@
+"""Self time of the program's codec:issue spans (host time issuing the decode programs, the eager stack and index ops included) per user MiB read."""
+import programspans
+
+LAYER = "codec"
+UNIT = "ms/MiB"
+SOURCE = "program_span"
+MOVES = "read_MiBps"
+
+
+def read(w):
+    return programspans.per_mib_ms(w, "read", "codec:issue")

@@ -24,7 +24,6 @@ LSB-discrimination trick as the paper (which relies on 4 KiB alignment).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import numpy as np
@@ -70,6 +69,7 @@ from repro.core.zns import (
     make_array_drives,
 )
 from repro.integrity.checksum import crc32c_many
+from repro.obs.hostspans import host_span, spanned
 
 
 class IntegrityError(RuntimeError):
@@ -368,11 +368,6 @@ class ZapRAIDArray:
         # None: the standalone functional array is unchanged.
         self.append_plan_fn = None   # (info, [(s_i, drive_idx)]) -> issue order
         self.commit_listener = None  # (info, built, per_drive_off) -> None
-        # Observes every fused-encode sync: (info, n_stripes, host_us).  The
-        # timed pipeline uses it to thread encode completions through the
-        # engine's accounting so latency stats stay honest about host-side
-        # codec stalls (virtual time is unaffected: the encode is host work).
-        self.encode_listener = None
         # Observability hook (repro.obs via repro.core.handlers): called as
         # ``obs_event(name, **args)`` at instrumentation points the array
         # alone can see -- degraded decodes, GC pass begin/end.  None (the
@@ -682,21 +677,22 @@ class ZapRAIDArray:
 
     def write(self, lba: int, data: np.ndarray) -> None:
         """Write ``data`` (n_blocks x block_bytes uint8) at logical ``lba``."""
-        data = np.asarray(data, dtype=np.uint8)
-        if data.ndim == 1:
-            data = data.reshape(1, -1)
-        n = data.shape[0]
-        assert data.shape[1] == self.zns_cfg.block_bytes
-        assert 0 <= lba and lba + n <= self.cfg.logical_blocks, (lba, n)
-        seg_class = self._classify(n)
-        if self.cfg.batched:
-            self._append_blocks(
-                seg_class, np.arange(lba, lba + n, dtype=np.int64), data, 0
-            )
-        else:
-            for i in range(n):
-                self._append_block(seg_class, lba + i, data[i], 0)
-        self.stats.host_blocks_written += n
+        with host_span("array", "stage"):
+            data = np.asarray(data, dtype=np.uint8)
+            if data.ndim == 1:
+                data = data.reshape(1, -1)
+            n = data.shape[0]
+            assert data.shape[1] == self.zns_cfg.block_bytes
+            assert 0 <= lba and lba + n <= self.cfg.logical_blocks, (lba, n)
+            seg_class = self._classify(n)
+            if self.cfg.batched:
+                self._append_blocks(
+                    seg_class, np.arange(lba, lba + n, dtype=np.int64), data, 0
+                )
+            else:
+                for i in range(n):
+                    self._append_block(seg_class, lba + i, data[i], 0)
+            self.stats.host_blocks_written += n
         self.maybe_gc()
 
     def _classify(self, n_blocks: int) -> int:
@@ -837,6 +833,7 @@ class ZapRAIDArray:
                 self._sync_pending()
                 progressed = True
 
+    @spanned("array", "commit")
     def flush(self) -> None:
         """Timeout path (§3.5): pad partial in-flight stripes and commit, then
         flush staged Zone-Append groups, then persist pending mapping blocks.
@@ -920,6 +917,7 @@ class ZapRAIDArray:
 
     # -- stripe construction ---------------------------------------------------
 
+    @spanned("array", "build")
     def _build_stripe(
         self, ost: _OpenSegment, stripe: _InFlightStripe, stripe_seq: int
     ) -> dict:
@@ -978,6 +976,7 @@ class ZapRAIDArray:
             "meta_gids": stripe.meta_gids.reshape(k, c),
         }
 
+    @spanned("array", "build")
     def _build_group(
         self, ost: _OpenSegment, raws: list[_InFlightStripe], seq0: int
     ) -> dict:
@@ -1091,6 +1090,7 @@ class ZapRAIDArray:
 
     # -- commit paths -----------------------------------------------------------
 
+    @spanned("array", "commit")
     def _commit_zone_write(self, ost: _OpenSegment, built: dict) -> None:
         """Ordered Zone Write commit: every chunk lands at the static offset."""
         info = ost.info
@@ -1149,6 +1149,7 @@ class ZapRAIDArray:
             self._pending_group = None
             self._commit_built_group(grp)
 
+    @spanned("array", "commit")
     def _commit_built_group(self, grp: dict) -> None:
         """Materialize the group's device parity and commit it to the drives.
 
@@ -1173,12 +1174,7 @@ class ZapRAIDArray:
         if scheme.mirror:
             parity_all = grp["data_all"]
         elif m:
-            t0 = time.perf_counter() if self.encode_listener else 0.0
             parity_np = codec.materialize(grp["parity_dev"])
-            if self.encode_listener is not None:
-                self.encode_listener(
-                    info, s_count, (time.perf_counter() - t0) * 1e6
-                )
             parity_all = kops.unpack_bytes_np(parity_np)[:s_count].reshape(
                 s_count, m, c, bb
             )
@@ -1248,6 +1244,7 @@ class ZapRAIDArray:
         if narrow and self.obs_event is not None:
             self.obs_event("commit_narrow.end", seg_id=info.seg_id)
 
+    @spanned("array", "commit")
     def _commit_group_legacy(self, ost: _OpenSegment) -> None:
         """Per-stripe build + per-command commit (``batched=False``)."""
         info = ost.info
@@ -1309,6 +1306,7 @@ class ZapRAIDArray:
         if narrow and self.obs_event is not None:
             self.obs_event("commit_narrow.end", seg_id=info.seg_id)
 
+    @spanned("array", "bookkeep")
     def _finish_stripe_bookkeeping(
         self, ost: _OpenSegment, built: dict, per_drive_off: dict[int, int]
     ) -> None:
@@ -1358,8 +1356,10 @@ class ZapRAIDArray:
                     if self.cache is not None:  # overwrite coherence point
                         self.cache.refresh_one(lba << 1, built["data"][role, b])
         if self.commit_listener is not None:
-            self.commit_listener(info, built, per_drive_off)
+            with host_span("service", "handle"):
+                self.commit_listener(info, built, per_drive_off)
 
+    @spanned("array", "bookkeep")
     def _finish_group_bookkeeping(
         self,
         ost: _OpenSegment,
@@ -1440,19 +1440,21 @@ class ZapRAIDArray:
             if self.cache is not None and ui.size:  # overwrite coherence point
                 self.cache.refresh_many(lba_u << 1, data_f[ui])
         if self.commit_listener is not None:
-            for s_i in range(s_count):
-                built = {
-                    "seq": int(seqs[s_i]),
-                    "data": codeword[s_i, :k],
-                    "parity": parity_all[s_i],
-                    "data_oob": grp["data_oob"][s_i],
-                    "par_oob": grp["par_oob"][s_i],
-                    "lbas": grp["lbas_all"][s_i],
-                    "ts": grp["ts_all"][s_i],
-                    "meta_gids": grp["gids_all"][s_i],
-                }
-                per_drive_off = {d: int(offsets[s_i, d]) for d in range(n)}
-                self.commit_listener(info, built, per_drive_off)
+            # the pipeline's completion handler, once per stripe of the group
+            with host_span("service", "handle"):
+                for s_i in range(s_count):
+                    built = {
+                        "seq": int(seqs[s_i]),
+                        "data": codeword[s_i, :k],
+                        "parity": parity_all[s_i],
+                        "data_oob": grp["data_oob"][s_i],
+                        "par_oob": grp["par_oob"][s_i],
+                        "lbas": grp["lbas_all"][s_i],
+                        "ts": grp["ts_all"][s_i],
+                        "meta_gids": grp["gids_all"][s_i],
+                    }
+                    per_drive_off = {d: int(offsets[s_i, d]) for d in range(n)}
+                    self.commit_listener(info, built, per_drive_off)
 
     def _invalidate_many(self, pbas: np.ndarray) -> None:
         """Vectorized ``_invalidate`` (old copies superseded by a group)."""
@@ -1533,6 +1535,7 @@ class ZapRAIDArray:
 
     # ------------------------------------------------------------------ reads
 
+    @spanned("array", "fetch")
     def read(self, lba: int, n_blocks: int = 1) -> np.ndarray:
         self._sync_pending()  # read-your-writes: deferred group must land
         self.stats.reads += n_blocks
@@ -1714,6 +1717,7 @@ class ZapRAIDArray:
 
     # -- degraded read (§3.5) -------------------------------------------------
 
+    @spanned("array", "reconstruct")
     def _degraded_read(self, seg_id: int, failed_drive: int, off: int) -> np.ndarray:
         self.stats.degraded_reads += 1
         rec = self.segments[seg_id]
@@ -1880,6 +1884,7 @@ class ZapRAIDArray:
             }
         return seq, members
 
+    @spanned("array", "reconstruct")
     def _reconstruct_chunks(
         self,
         rec: _SegmentRecord,
@@ -2145,6 +2150,7 @@ class ZapRAIDArray:
 
     # -------------------------------------------------------------------- GC
 
+    @spanned("array", "gc")
     def maybe_gc(self) -> None:
         while self.free_segment_count() < self.cfg.gc_free_segments_low:
             before = self.free_segment_count()
@@ -2280,6 +2286,7 @@ class ZapRAIDArray:
         mg, mb = pack(m_gids, m_blocks)
         return ul, ub, mg, mb
 
+    @spanned("array", "gc")
     def gc_once(self) -> bool:
         """Greedy GC (§4): collect the best cost-benefit victim's live blocks
         and restage them through the normal write path, then reclaim the

@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core.array import ZapRaidConfig, ZapRAIDArray
 from repro.core.zns import ZnsConfig
+from repro.obs.hostspans import spanned
 
 
 @dataclasses.dataclass
@@ -110,7 +111,6 @@ class HandlerPipeline:
             self.tracer = None
             self._obs_marks: dict[str, float] = {}
             array.commit_listener = self._on_stripe_commit
-            array.encode_listener = self._on_group_encode
             if array.cfg.append_order == "timed":
                 array.append_plan_fn = self._plan_group
 
@@ -242,6 +242,10 @@ class HandlerPipeline:
 
     # -- timed-mode events ---------------------------------------------------
 
+    # every timed-mode event handler and tick actor is one ``service:handle``
+    # host span (repro.obs.hostspans)
+
+    @spanned("service", "handle")
     def _ev_write(self, lba: int, data: np.ndarray, cb, tenant: str, t_submit: float):
         eng = self.engine
         self.counters["dispatch"] += 1
@@ -259,6 +263,7 @@ class HandlerPipeline:
         # resolve pending requests through the commit listener
         self.array.write(lba, data)
 
+    @spanned("service", "handle")
     def _ev_read(self, lba: int, n_blocks: int, cb, tenant: str, t_submit: float):
         eng = self.engine
         self.counters["dispatch"] += 1
@@ -270,6 +275,7 @@ class HandlerPipeline:
         eng.at(t_dev + self.service.cpu_complete_us, self._ev_read_done,
                lba, out, cb, tenant, t_submit, t_dev - mark)
 
+    @spanned("service", "handle")
     def _ev_read_done(self, lba, out, cb, tenant, t_submit, device_us):
         self.counters["completion"] += 1
         self.completed.append((lba, out))
@@ -279,6 +285,7 @@ class HandlerPipeline:
         if cb:
             cb(out)
 
+    @spanned("service", "handle")
     def _ev_write_done(self, req: _PendingWrite):
         self.counters["completion"] += 1
         self.counters["indexing"] += 1
@@ -327,6 +334,7 @@ class HandlerPipeline:
         self._flush_tick_armed = True
         self.engine.after(self.flush_interval_us, self._ev_flush_tick_auto)
 
+    @spanned("service", "handle")
     def _ev_flush_tick_auto(self) -> None:
         self._flush_tick_armed = False
         self._ev_flush_tick()
@@ -357,21 +365,6 @@ class HandlerPipeline:
         self._barriers[info.seg_id] = group_done
         self.counters["segment_state"] += 1
         return order
-
-    def _on_group_encode(self, info, n_stripes: int, host_us: float) -> None:
-        """Encode-completion event from the device-resident datapath.
-
-        The fused group encode runs on the accelerator while the committer
-        prepares the drive payloads; the sync stall the committer actually
-        paid (host wall time of the materialize) is threaded into the
-        recorder -- ``notes["encode_sync_us"]`` totals the stall and
-        ``note_counts["encode_sync_us"]`` counts the groups -- so timed-mode
-        stats stay honest about codec cost.  Virtual
-        time is untouched: with the timed pipeline attached, group commits
-        are synchronous (the group barrier is already a sync point)."""
-        # one note per group: notes["encode_sync_us"] accumulates the total
-        # stall, note_counts["encode_sync_us"] counts encoded groups
-        self.recorder.note("encode_sync_us", host_us)
 
     def _on_stripe_commit(self, info, built, per_drive_off):
         """Resolve pending writes covered by a just-persisted stripe."""
@@ -491,6 +484,7 @@ class HandlerPipeline:
         else:
             self.engine.at(at, self._ev_rebuild_start, drive_idx, interval_us)
 
+    @spanned("service", "handle")
     def _ev_rebuild(self, drive_idx: int) -> None:
         eng = self.engine
         mark = eng.mark_io()
@@ -501,6 +495,7 @@ class HandlerPipeline:
                              max(eng.now, eng.io_watermark),
                              cat="background", drive=drive_idx)
 
+    @spanned("service", "handle")
     def _ev_rebuild_start(self, drive_idx: int, interval_us: float) -> None:
         arr = self.array
         eng = self.engine
@@ -529,6 +524,7 @@ class HandlerPipeline:
         else:
             eng.at(eng.now + interval_us, self._ev_rewiden)
 
+    @spanned("service", "handle")
     def _ev_rebuild_step(
         self, drive_idx: int, sealed: list, i: int, interval_us: float, scaffold: dict
     ) -> None:
@@ -557,6 +553,7 @@ class HandlerPipeline:
             # back to full width on the rebuilt drive set
             eng.at(eng.now + interval_us, self._ev_rewiden)
 
+    @spanned("service", "handle")
     def _ev_rewiden(self) -> None:
         arr = self.array
         eng = self.engine
@@ -586,6 +583,7 @@ class HandlerPipeline:
             watermark = self.array.cfg.gc_free_segments_low + 1
         self.engine.at(at, self._ev_gc_tick, interval_us, n_ticks, watermark)
 
+    @spanned("service", "handle")
     def _ev_gc_tick(self, interval_us: float, remaining: int, watermark: int) -> None:
         arr = self.array
         eng = self.engine
@@ -619,6 +617,7 @@ class HandlerPipeline:
         self.engine.at(at, self._ev_scrub_start,
                        interval_us, n_passes, yield_to_foreground)
 
+    @spanned("service", "handle")
     def _ev_scrub_start(
         self, interval_us: float, remaining: int, yield_fg: bool
     ) -> None:
@@ -638,6 +637,7 @@ class HandlerPipeline:
                                self._ev_scrub_start,
                                interval_us, remaining - 1, yield_fg)
 
+    @spanned("service", "handle")
     def _ev_scrub_step(
         self, sealed: list, i: int, interval_us: float, remaining: int,
         yield_fg: bool,

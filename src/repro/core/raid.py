@@ -21,6 +21,8 @@ import numpy as np
 from repro.core import gf
 from repro.kernels import ops
 from repro.kernels.backend import codec_mode
+from repro.obs import hostspans
+from repro.obs.hostspans import host_span, spanned
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,6 +94,11 @@ class StripeCodec:
     The codec mode is resolved once, here, from the backend when left unset
     (:mod:`repro.kernels.backend`): compiled Pallas kernels on a TPU, the
     jnp reference elsewhere.
+
+    Host spans (:mod:`repro.obs.hostspans`): ``codec:h2d`` (the copy to the
+    device), ``codec:issue`` (issuing an encode's or a decode's programs,
+    counted by method and argument shapes), ``codec:wait`` (the host
+    blocked on the result) and ``codec:d2h`` (the copy back).
     """
 
     def __init__(
@@ -107,6 +114,7 @@ class StripeCodec:
 
     # -- host<->device accounting -------------------------------------------
 
+    @spanned("codec", "h2d")
     def _to_device(self, packed_np: np.ndarray) -> jnp.ndarray:
         if self.copy_stats is not None:
             self.copy_stats.h2d_copies += 1
@@ -119,13 +127,21 @@ class StripeCodec:
 
     def materialize(self, out_dev: jnp.ndarray) -> np.ndarray:
         """Sync point: block on the device result and bring it to the host."""
-        out = np.asarray(out_dev)
+        if hostspans.current() is not None:
+            # recording: wait apart from the copy, to time each.  Not
+            # otherwise: a second blocking call costs a thread wake-up
+            # whenever the result is not ready yet
+            with host_span("codec", "wait"):
+                out_dev.block_until_ready()
+        with host_span("codec", "d2h"):
+            out = np.asarray(out_dev)
         if self.copy_stats is not None:
             self.copy_stats.d2h_copies += 1
             self.copy_stats.d2h_bytes += out.nbytes
         return out
 
     # data: (k, n_i32) int32 packed chunk payloads
+    @spanned("codec", "issue", dispatch=True)
     def encode(self, data_i32: jnp.ndarray) -> jnp.ndarray:
         """Return (m, n_i32) parity chunks (empty for RAID-0)."""
         s = self.scheme
@@ -143,6 +159,7 @@ class StripeCodec:
             data_i32, s.m, use_pallas=self.use_pallas, interpret=self.interpret
         )
 
+    @spanned("codec", "issue", dispatch=True)
     def decode(
         self, surviving_i32: jnp.ndarray, surviving_roles: tuple[int, ...]
     ) -> jnp.ndarray:
@@ -182,6 +199,7 @@ class StripeCodec:
         )
 
     # batched (stripe-group) datapath: data (S, k, n_i32) int32
+    @spanned("codec", "issue", dispatch=True)
     def encode_batch(self, data_i32: jnp.ndarray) -> jnp.ndarray:
         """Encode S stripes at once: (S, k, n) -> (S, m, n) parity.
 
@@ -203,6 +221,7 @@ class StripeCodec:
             data_i32, s.m, use_pallas=self.use_pallas, interpret=self.interpret
         )
 
+    @spanned("codec", "issue", dispatch=True)
     def decode_batch(
         self, surviving_i32: jnp.ndarray, surviving_roles: tuple[int, ...]
     ) -> jnp.ndarray:
@@ -294,6 +313,7 @@ class StripeCodec:
 
     # -- device-resident group entry points (donated buffers, async) ---------
 
+    @spanned("codec", "issue", dispatch=True)
     def encode_batch_async(self, packed_np: np.ndarray) -> jnp.ndarray:
         """Dispatch a fused group encode and return the device array.
 
@@ -322,6 +342,7 @@ class StripeCodec:
                 packed, s.m, use_pallas=self.use_pallas, interpret=self.interpret
             )
 
+    @spanned("codec", "issue", dispatch=True)
     def decode_batch_async(
         self, packed_np: np.ndarray, surviving_roles: tuple[int, ...]
     ) -> jnp.ndarray:
@@ -376,6 +397,7 @@ def _meta_unrows(raw: np.ndarray, c: int) -> tuple[np.ndarray, np.ndarray]:
     return lbas, ts
 
 
+@spanned("codec", "meta")
 def parity_oob(
     codec: "StripeCodec", data_lbas: np.ndarray, data_ts: np.ndarray
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -410,6 +432,7 @@ def _meta_unrows_batch(raw: np.ndarray, c: int) -> tuple[np.ndarray, np.ndarray]
     return lbas, ts
 
 
+@spanned("codec", "meta")
 def parity_oob_batch(
     codec: "StripeCodec", data_lbas: np.ndarray, data_ts: np.ndarray
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -421,6 +444,7 @@ def parity_oob_batch(
     return _meta_unrows_batch(enc, c)
 
 
+@spanned("codec", "meta")
 def decode_meta_batch(
     codec: "StripeCodec",
     surviving_lbas: np.ndarray,
@@ -435,6 +459,7 @@ def decode_meta_batch(
     return _meta_unrows_batch(dec.reshape(rows.shape[0], codec.scheme.k, -1), c)
 
 
+@spanned("codec", "meta")
 def decode_meta(
     codec: "StripeCodec",
     surviving_lbas: np.ndarray,

@@ -41,6 +41,7 @@ from typing import Optional
 import numpy as np
 
 from repro.integrity.checksum import crc32c_many
+from repro.obs.hostspans import spanned
 
 OOB_DTYPE = np.dtype([("lba", "<u8"), ("ts", "<u8"), ("stripe", "<u4")])
 OOB_ENTRY_BYTES = 20  # paper §3.1: 8 (LBA) + 8 (timestamp) + 4 (stripe id)
@@ -218,6 +219,7 @@ class SimZnsDrive:
             if not self._commit_block(zone, blocks[i], oobs[i], crcs[i]):
                 raise DeviceCrashed(f"crash on drive={self.drive_id}")
 
+    @spanned("media", "append")
     def zone_write(
         self, zone: int, offset: int, blocks: np.ndarray, oobs: np.ndarray, crcs=None
     ) -> None:
@@ -234,6 +236,7 @@ class SimZnsDrive:
         self._check_alive()
         self._open_zone(zone)
 
+    @spanned("media", "append")
     def zone_append_commit(
         self, zone: int, blocks: np.ndarray, oobs: np.ndarray, crcs=None
     ) -> int:
@@ -249,6 +252,7 @@ class SimZnsDrive:
         self._commit_blocks(zone, blocks, oobs, crcs)
         return off
 
+    @spanned("media", "append")
     def zone_append_commit_many(
         self, zone: int, chunks: np.ndarray, oobs: np.ndarray, crcs=None
     ) -> np.ndarray:
@@ -275,6 +279,7 @@ class SimZnsDrive:
 
     # -- reads --------------------------------------------------------------
 
+    @spanned("media", "read")
     def read(self, zone: int, offset: int, n_blocks: int) -> np.ndarray:
         self._check_alive()
         return self.data[zone, offset : offset + n_blocks]
@@ -283,11 +288,13 @@ class SimZnsDrive:
         self._check_alive()
         return self.oob[zone, offset : offset + n_blocks]
 
+    @spanned("media", "read")
     def read_blocks(self, zone: int, offsets: np.ndarray) -> np.ndarray:
         """Gather scattered blocks of one zone: (len(offsets), block_bytes)."""
         self._check_alive()
         return self.data[zone, np.asarray(offsets, dtype=np.int64)]
 
+    @spanned("media", "read")
     def read_scattered(self, zones: np.ndarray, offsets: np.ndarray) -> np.ndarray:
         """Cross-zone gather: block ``offsets[i]`` of ``zones[i]`` for each i.
 

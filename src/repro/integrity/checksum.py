@@ -27,6 +27,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.obs.hostspans import spanned
+
 __all__ = ["CRC_BYTES", "crc32c", "crc32c_many", "crc32c_pack", "verify_many"]
 
 CRC_BYTES = 4  # stored checksum width (uint32, little-endian when packed)
@@ -79,6 +81,7 @@ def crc32c(data: bytes | bytearray | memoryview | np.ndarray) -> int:
     return crc ^ 0xFFFFFFFF
 
 
+@spanned("checksum", "crc32c")
 def crc32c_many(blocks: np.ndarray) -> np.ndarray:
     """CRC32C of each row: ``(N, L) uint8 -> (N,) uint32``.
 

@@ -85,6 +85,7 @@ def gf256_matmul_batch(
         out_specs=pl.BlockSpec((1, m, bn), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((s, m, n), jnp.int32),
         interpret=resolve_interpret(interpret),
+        name="gf256_matmul_batch",
     )(coeff.astype(jnp.int32), data)
 
 
@@ -111,4 +112,5 @@ def gf256_matmul(
         out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=resolve_interpret(interpret),
+        name="gf256_matmul",
     )(coeff.astype(jnp.int32), data)

@@ -28,6 +28,7 @@ from repro.kernels.backend import LANES, codec_mode
 from repro.kernels.gf256_matmul import gf256_matmul, gf256_matmul_batch
 from repro.kernels.parity_xor import parity_xor, parity_xor_batch
 from repro.kernels.ssd_scan import ssd_scan
+from repro.obs.hostspans import spanned
 
 
 @functools.lru_cache(maxsize=None)
@@ -283,6 +284,7 @@ def rs_decode_batch_device(
     )
 
 
+@spanned("codec", "pack")
 def pack_bytes_np(data_u8: np.ndarray) -> np.ndarray:
     """Host-side ``pack_bytes``: a free dtype view, no device dispatch.
 
@@ -294,6 +296,7 @@ def pack_bytes_np(data_u8: np.ndarray) -> np.ndarray:
     return data_u8.view(np.int32)
 
 
+@spanned("codec", "pack")
 def unpack_bytes_np(data_i32: np.ndarray) -> np.ndarray:
     """Host-side ``unpack_bytes``: a free dtype view of an int32 buffer."""
     return np.ascontiguousarray(data_i32).view(np.uint8)

@@ -65,6 +65,7 @@ def parity_xor_batch(
         out_specs=pl.BlockSpec((1, 1, bn), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((s, 1, n), jnp.int32),
         interpret=resolve_interpret(interpret),
+        name="xor_parity_batch",
     )(data)
     return out[:, 0]
 
@@ -91,5 +92,6 @@ def parity_xor(
         out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
         interpret=resolve_interpret(interpret),
+        name="xor_parity",
     )(data)
     return out[0]

@@ -39,6 +39,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.obs.hostspans import host_span
 from repro.service.qos import THROUGHPUT, QosClass, TokenBucket
 from repro.service.request import (
     DONE,
@@ -155,8 +156,14 @@ class BlockDeviceService:
         return req
 
     # -- events --------------------------------------------------------------
+    # each is a host span carrying the request's seq (repro.obs.hostspans);
+    # calls back into the caller are ``client:callback``, not service time
 
     def _ev_arrive(self, req: IoRequest) -> None:
+        with host_span("service", "arrive", req=req.seq):
+            self._arrive(req)
+
+    def _arrive(self, req: IoRequest) -> None:
         ten = self.tenants[req.tenant]
         req.t_submit = self.engine.now
         req.deadline = req.t_submit + ten.qos.deadline_us
@@ -178,7 +185,8 @@ class BlockDeviceService:
                            status=REJECTED)
             self.cq.push(req)
             if req.cb_fn:
-                req.cb_fn(req)
+                with host_span("client", "callback"):
+                    req.cb_fn(req)
             return
         cache = self.pipe.array.cache if self.cache_bypass else None
         if (
@@ -245,6 +253,10 @@ class BlockDeviceService:
         return best.queue.popleft()
 
     def _dispatch(self, req: IoRequest) -> None:
+        with host_span("service", "dispatch", req=req.seq):
+            self._dispatch_one(req)
+
+    def _dispatch_one(self, req: IoRequest) -> None:
         ten = self.tenants[req.tenant]
         req.status = INFLIGHT
         req.t_dispatch = self.engine.now
@@ -274,6 +286,10 @@ class BlockDeviceService:
             )
 
     def _ev_complete(self, req: IoRequest, result) -> None:
+        with host_span("service", "complete", req=req.seq):
+            self._complete(req, result)
+
+    def _complete(self, req: IoRequest, result) -> None:
         ten = self.tenants[req.tenant]
         req.status = DONE
         req.t_done = self.engine.now
@@ -296,7 +312,8 @@ class BlockDeviceService:
         )
         self.cq.push(req)
         if req.cb_fn:
-            req.cb_fn(req)
+            with host_span("client", "callback"):
+                req.cb_fn(req)
         self._pump()
 
     def _arm_token_wake(self, now: float) -> None:

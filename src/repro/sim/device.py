@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.core import perfmodel as pm
 from repro.core.zns import CrashBudget, SimZnsDrive, ZnsConfig
+from repro.obs.hostspans import host_span, spanned
 from repro.sim.engine import Engine
 
 
@@ -112,6 +113,7 @@ class TimedDrive(SimZnsDrive):
         i = int(np.argmin(self.channels))
         self.channels[i] = t_done
 
+    @spanned("media", "book")
     def book_zone_write(self, zone: int, n_blocks: int, floor: float) -> float:
         """Book one Zone Write command; returns its completion time."""
         start = self._grab_channel(max(floor, float(self.t_zone_free[zone])))
@@ -152,6 +154,7 @@ class TimedDrive(SimZnsDrive):
                              zone=zone, n_blocks=n_blocks, qd=qd_now)
         return done
 
+    @spanned("media", "book")
     def book_read(self, n_blocks: int, floor: float) -> float:
         """Book a read of ``n_blocks`` (channel contention; no wp ordering).
 
@@ -189,6 +192,9 @@ class TimedDrive(SimZnsDrive):
 
     # -- timed command surface (functional op + booking) ----------------------
 
+    # the booking of commands is the ``media:book`` host span; the media
+    # update (``super()``) is ``media:append`` / ``media:read``
+
     def zone_write(self, zone: int, offset: int, blocks, oobs, crcs=None) -> None:
         super().zone_write(zone, offset, blocks, oobs, crcs)
         done = self.book_zone_write(zone, blocks.shape[0], self.engine.now)
@@ -196,28 +202,31 @@ class TimedDrive(SimZnsDrive):
 
     def zone_append_commit(self, zone: int, blocks, oobs, crcs=None) -> int:
         off = super().zone_append_commit(zone, blocks, oobs, crcs)
-        planned = self._planned.get(zone)
-        if planned:
-            done = planned.popleft()
-            self.engine.touch_io(done)
-        else:
-            done = self.book_append(zone, blocks.shape[0], self.engine.now)
-        self.chunk_done[(zone, off)] = done
-        return off
-
-    def zone_append_commit_many(self, zone: int, chunks, oobs, crcs=None) -> np.ndarray:
-        offs = super().zone_append_commit_many(zone, chunks, oobs, crcs)
-        planned = self._planned.get(zone)
-        c = chunks.shape[1]
-        for off in offs:
-            # the per-zone planned queue is in completion-time order, which
-            # is exactly the per-zone issue order of the group committer
+        with host_span("media", "book"):
+            planned = self._planned.get(zone)
             if planned:
                 done = planned.popleft()
                 self.engine.touch_io(done)
             else:
-                done = self.book_append(zone, c, self.engine.now)
-            self.chunk_done[(zone, int(off))] = done
+                done = self.book_append(zone, blocks.shape[0], self.engine.now)
+            self.chunk_done[(zone, off)] = done
+        return off
+
+    def zone_append_commit_many(self, zone: int, chunks, oobs, crcs=None) -> np.ndarray:
+        offs = super().zone_append_commit_many(zone, chunks, oobs, crcs)
+        with host_span("media", "book"):
+            planned = self._planned.get(zone)
+            c = chunks.shape[1]
+            for off in offs:
+                # the per-zone planned queue is in completion-time order,
+                # which is exactly the per-zone issue order of the group
+                # committer
+                if planned:
+                    done = planned.popleft()
+                    self.engine.touch_io(done)
+                else:
+                    done = self.book_append(zone, c, self.engine.now)
+                self.chunk_done[(zone, int(off))] = done
         return offs
 
     def read(self, zone: int, offset: int, n_blocks: int):
@@ -317,6 +326,7 @@ def make_timed_drives(
     ]
 
 
+@spanned("media", "book")
 def plan_group_appends(
     drives: list[TimedDrive],
     zone_ids: tuple[int, ...],

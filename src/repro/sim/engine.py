@@ -26,6 +26,8 @@ import heapq
 import math
 from typing import Any, Callable
 
+from repro.obs.hostspans import spanned
+
 
 class Engine:
     """Virtual clock (microseconds) + event heap."""
@@ -49,6 +51,7 @@ class Engine:
 
     # -- execution ----------------------------------------------------------
 
+    @spanned("service", "loop")
     def run(self, until: float = math.inf) -> int:
         """Fire events in time order until the heap drains (or ``until``).
 

@@ -182,8 +182,10 @@ def test_copy_counters_count_groups_not_stripes():
     assert arr.stats.h2d_bytes > 0 and arr.stats.d2h_bytes > 0
 
 
-def test_timed_pipeline_reports_encode_sync():
-    """Timed mode threads encode completions into the latency recorder."""
+def test_timed_pipeline_reports_encode_sync(host_spans):
+    """The timed pipeline's group commits each wait on their encodes once:
+    one ``codec:wait`` host span for the payload parity and one for the
+    metadata parity of every group built."""
     from repro.core.handlers import HandlerPipeline
     from repro.sim import Request
 
@@ -195,10 +197,13 @@ def test_timed_pipeline_reports_encode_sync():
     rng = np.random.default_rng(29)
     reqs = [Request(float(i) * 10.0, "t", "W", int(rng.integers(0, 250)), 1)
             for i in range(64)]
-    rec = pipe.replay(reqs, payload_fn=lambda r: rng.integers(
+    pipe.replay(reqs, payload_fn=lambda r: rng.integers(
         0, 256, (r.n_blocks, BB), dtype=np.uint8))
-    assert rec.note_counts.get("encode_sync_us", 0) >= 1  # groups encoded
-    assert rec.notes.get("encode_sync_us", 0.0) >= 0.0
+    spans = host_spans.snapshot()["spans"]
+    groups = spans["array:build"]["count"]
+    assert groups >= 1                                    # groups encoded
+    assert spans["codec:wait"]["count"] == 2 * groups
+    assert spans["codec:wait"]["self_s"] >= 0.0
 
 
 # ------------------------------------------------------- L2P property test

@@ -15,6 +15,7 @@ from repro.core.handlers import HandlerPipeline
 from repro.core.zns import ZnsConfig
 from repro.obs import (
     Histogram,
+    HostSpans,
     MetricsRegistry,
     MetricsSampler,
     Tracer,
@@ -275,21 +276,35 @@ def test_sampler_does_not_keep_engine_alive():
 # ------------------------------------------------------ bit-identity gate
 
 
-@pytest.mark.parametrize("scheme", SCHEMES)
-def test_tracing_is_observe_only(scheme):
-    """Tracing+metrics on vs off: media, OOB, L2P, and the virtual clock
-    must be bit-identical -- the obs layer may never book device time."""
+@pytest.mark.parametrize(
+    "scheme,host", [pytest.param(s, False, id=s) for s in SCHEMES]
+    + [pytest.param(s, True, id=f"{s}-host_spans") for s in SCHEMES])
+def test_tracing_is_observe_only(scheme, host):
+    """Tracing+metrics on vs off (and, with ``host``, the wall-clock host
+    span recorder on vs off): media, OOB, L2P, and the virtual clock must
+    be bit-identical -- the obs layer may never book device time."""
     results = []
     for obs in (False, True):
         pipe = _timed_pipe(scheme=scheme, logical_blocks=96)
         _precondition(pipe, 96)
-        if obs:
+        rec = None
+        if obs and host:
+            rec = HostSpans().install()
+        elif obs:
             pipe.attach_obs()
             sampler = MetricsSampler(
                 pipe.engine, MetricsRegistry(), standard_collector(pipe),
                 interval_us=20.0)
             sampler.start(0.0)
-        _workload(pipe, rounds=2, fail=True)
+        try:
+            _workload(pipe, rounds=2, fail=True)
+        finally:
+            if rec is not None:
+                rec.uninstall()
+        if rec is not None:   # the recorder saw the run it left unchanged
+            spans = rec.snapshot()["spans"]
+            assert spans["array:reconstruct"]["count"] > 0
+            assert spans["service:loop"]["count"] > 0
         results.append(pipe)
     off, on = results
     assert off.engine.now == on.engine.now

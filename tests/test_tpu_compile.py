@@ -5,15 +5,18 @@ has no Mosaic lowering, for one), so these tests hold the main path's
 kernels to the real compiler at its real shapes: stripe groups of S=256 at
 4 KiB chunks (n=1024 lanes) and 16 KiB chunks (n=4096, the checkpoint's
 ``chunk_blocks=4``); RAID-5 (3+1) XOR encode and decode; RAID-6 (2+2) RS
-encode and RS decode for every survivor set; and the single-stripe XOR that
-``checkpoint/state_parity.py`` dispatches.  Each case compiles through the
-``ops`` entry point the codec calls and asserts a Pallas TPU kernel
-(``tpu_custom_call``) in the compiled program.
+encode and RS decode for every survivor set; the single-stripe XOR that
+``checkpoint/state_parity.py`` dispatches; and the single-stripe RS product
+of a RAID-6 degraded read.  Each case compiles through the ``ops`` entry
+point the codec calls and asserts a Pallas TPU kernel (``tpu_custom_call``)
+in the compiled program, under the kernel's stable name (``name=`` on its
+``pallas_call``), which a profile shows as the kernel's op.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import itertools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +67,13 @@ def _compiled_text(fn, *shapes, sharding) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _has_kernel(text: str, name: str) -> bool:
+    """A Pallas TPU kernel whose instruction carries ``name``."""
+    return re.search(
+        rf"%{name}(\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text) is not None
+
+
 @pytest.mark.parametrize("n", LANES)
 def test_raid5_xor_encode_decode_compiles(one_chip, n):
     """k=3 XOR: the encode of 3 data chunks and the decode of 3 survivors
@@ -73,6 +83,7 @@ def test_raid5_xor_encode_decode_compiles(one_chip, n):
         sharding=one_chip,
     )
     assert "tpu_custom_call" in text
+    assert _has_kernel(text, "xor_parity_batch")
 
 
 @pytest.mark.parametrize("n", LANES)
@@ -83,6 +94,7 @@ def test_raid6_rs_encode_compiles(one_chip, n):
         sharding=one_chip,
     )
     assert "tpu_custom_call" in text
+    assert _has_kernel(text, "gf256_matmul_batch")
 
 
 @pytest.mark.parametrize("survivors", list(itertools.combinations(range(4), 2)))
@@ -94,6 +106,7 @@ def test_raid6_rs_decode_compiles(one_chip, n, survivors):
         sharding=one_chip,
     )
     assert "tpu_custom_call" in text
+    assert _has_kernel(text, "gf256_matmul_batch")
 
 
 @pytest.mark.parametrize("n", LANES)
@@ -102,3 +115,16 @@ def test_state_parity_single_stripe_xor_compiles(one_chip, n):
         lambda x: ops.xor_parity(x, **COMPILED), (3, n), sharding=one_chip
     )
     assert "tpu_custom_call" in text
+    assert _has_kernel(text, "xor_parity")
+
+
+@pytest.mark.parametrize("n", LANES)
+def test_raid6_single_stripe_rs_decode_compiles(one_chip, n):
+    """A RAID-6 degraded read decodes one stripe: the (2, 2) decode matrix
+    of survivors 1 and 3 times their (2, n) chunks."""
+    dec = jnp.asarray(gf.rs_decode_matrix(2, 2, (1, 3)), jnp.int32)
+    text = _compiled_text(
+        lambda x: ops.rs_matmul(dec, x, **COMPILED), (2, n), sharding=one_chip
+    )
+    assert "tpu_custom_call" in text
+    assert _has_kernel(text, "gf256_matmul")
