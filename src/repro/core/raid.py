@@ -80,7 +80,9 @@ class StripeCodec:
 
     * ``encode_np``/``decode_np`` and their ``_batch`` variants -- blocking
       uint8-in/uint8-out convenience wrappers (host packing is a free dtype
-      view; one device round trip per call);
+      view; one device round trip per call).  A single-parity ``decode_np``
+      that lost a data role brings back only the rebuilt row and puts the
+      stripe's rows together on the host;
     * ``encode_batch_async``/``decode_batch_async`` -- the device-resident
       group datapath: take an int32-packed host buffer the caller gives up
       (an arena gather), donate it to XLA, and return the *un-materialized*
@@ -163,7 +165,12 @@ class StripeCodec:
     def decode(
         self, surviving_i32: jnp.ndarray, surviving_roles: tuple[int, ...]
     ) -> jnp.ndarray:
-        """Reconstruct all k data chunks from k surviving codeword rows."""
+        """Reconstruct the data chunks from k surviving codeword rows.
+
+        Returns the k data rows, (k, n), except where a single-parity stripe
+        lost a data role: then only that row, (n,), the XOR of the survivors.
+        The other data rows are among the survivors already, and
+        :meth:`decode_np` puts them together on the host."""
         s = self.scheme
         if s.m == 0:
             raise ValueError("RAID-0 cannot decode lost chunks")
@@ -183,16 +190,11 @@ class StripeCodec:
             order = [roles.index(i) for i in range(s.k)]
             return surviving_i32[jnp.array(order)]
         if s.m == 1:
-            # Single parity: lost data chunk = XOR of the survivors.
-            lost = set(range(s.k)) - set(roles)
-            assert len(lost) == 1
-            lost_role = lost.pop()
-            rec = ops.xor_parity(
+            # Single parity: the lost data chunk is the XOR of the survivors.
+            assert len(set(range(s.k)) - set(roles)) == 1
+            return ops.xor_parity(
                 surviving_i32, use_pallas=self.use_pallas, interpret=self.interpret
             )
-            rows = {role: surviving_i32[i] for i, role in enumerate(roles) if role < s.k}
-            rows[lost_role] = rec
-            return jnp.stack([rows[i] for i in range(s.k)], axis=0)
         return ops.rs_decode(
             surviving_i32, roles, s.k, s.m,
             use_pallas=self.use_pallas, interpret=self.interpret,
@@ -227,7 +229,7 @@ class StripeCodec:
     ) -> jnp.ndarray:
         """Reconstruct S stripes' data chunks from survivors sharing one role
         set: (S, k, n) survivors -> (S, k, n) data, bit-identical to stacking
-        ``decode`` over the S stripes."""
+        :meth:`decode_np` over the S stripes."""
         s = self.scheme
         if s.m == 0:
             raise ValueError("RAID-0 cannot decode lost chunks")
@@ -260,10 +262,27 @@ class StripeCodec:
         )
 
     def decode_np(self, surviving: np.ndarray, surviving_roles: tuple[int, ...]) -> np.ndarray:
-        """Byte-level convenience wrapper (uint8 in/out) used by recovery paths."""
+        """Byte-level convenience wrapper (uint8 in/out) used by recovery paths.
+
+        One device round trip per call.  For single parity with a lost data
+        role the device computes only that row (the XOR of the survivors)
+        and only it comes back; the k rows are put together here on the
+        host, the others taken from ``surviving``."""
+        roles = tuple(surviving_roles)
         packed = self._to_device(ops.pack_bytes_np(surviving))
-        out = self.decode(packed, surviving_roles)
-        return ops.unpack_bytes_np(self.materialize(out))
+        out = self.materialize(self.decode(packed, roles))
+        if out.ndim == 2:
+            return ops.unpack_bytes_np(out)
+        # one rebuilt data row: every other data row survived
+        k = self.scheme.k
+        data = np.empty((k, surviving.shape[1]), np.uint8)
+        rebuilt = np.ones(k, bool)
+        for row, role in zip(surviving, roles):
+            if role < k:
+                data[role] = row
+                rebuilt[role] = False
+        data[rebuilt] = ops.unpack_bytes_np(out)
+        return data
 
     def encode_np(self, data: np.ndarray) -> np.ndarray:
         if not self.scheme.m:

@@ -12,7 +12,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core.array import ZapRaidConfig, ZapRAIDArray
+from repro.core.array import Stats, ZapRaidConfig, ZapRAIDArray
 from repro.core.l2p import NO_PBA, L2PTable, pack_pba, unpack_pba, unpack_pba_many
 from repro.core.raid import (
     StripeCodec,
@@ -24,6 +24,7 @@ from repro.core.raid import (
 )
 from repro.core.zns import ZnsConfig
 from repro.kernels import ops, ref
+from repro.obs import HostSpans
 
 BB = 256
 SCHEMES = [("raid0", 4), ("raid01", 4), ("raid4", 4), ("raid5", 4), ("raid6", 5)]
@@ -121,7 +122,11 @@ def test_encode_batch_bit_identical(scheme, n_drives, nbytes):
 
 @pytest.mark.parametrize("scheme,n_drives", SCHEMES[1:])  # raid0 cannot decode
 @pytest.mark.parametrize("nbytes", [512, 96])
-def test_decode_batch_every_survivor_subset(scheme, n_drives, nbytes):
+@pytest.mark.parametrize("permuted", [False, True])  # survivors in every order
+def test_decode_batch_every_survivor_subset(scheme, n_drives, nbytes, permuted):
+    """Every survivor subset decodes to the data, batched and per stripe, one
+    copy each way per ``decode_np``.  A single-parity stripe that lost a data
+    role brings back only the rebuilt row: n bytes, not k * n."""
     codec = _codec(scheme, n_drives)
     sch = codec.scheme
     rng = np.random.default_rng(hash((scheme, nbytes, "d")) % (1 << 31))
@@ -129,16 +134,25 @@ def test_decode_batch_every_survivor_subset(scheme, n_drives, nbytes):
     data = rng.integers(0, 256, (s_count, sch.k, nbytes), dtype=np.uint8)
     code = np.concatenate([data, codec.encode_batch_np(data)], axis=1)
     tested = 0
-    for surv in itertools.combinations(range(sch.n), sch.k):
-        if sch.mirror and not _mirror_ok(sch, surv):
+    for subset in itertools.combinations(range(sch.n), sch.k):
+        if sch.mirror and not _mirror_ok(sch, subset):
             continue
-        batch = codec.decode_batch_np(code[:, list(surv)], surv)
-        per = np.stack(
-            [codec.decode_np(code[s][list(surv)], surv) for s in range(s_count)]
-        )
-        assert np.array_equal(batch, per.reshape(batch.shape)), (scheme, surv)
-        assert np.array_equal(batch.reshape(s_count, sch.k, nbytes), data), surv
-        tested += 1
+        lost_data = set(range(sch.k)) - set(subset)
+        back = nbytes if sch.m == 1 and lost_data else sch.k * nbytes
+        orders = itertools.permutations(subset) if permuted else [subset]
+        for surv in orders:
+            batch = codec.decode_batch_np(code[:, list(surv)], surv)
+            codec.copy_stats = st = Stats()
+            per = np.stack(
+                [codec.decode_np(code[s][list(surv)], surv) for s in range(s_count)]
+            )
+            codec.copy_stats = None
+            assert (st.h2d_copies, st.h2d_bytes, st.d2h_copies, st.d2h_bytes) == (
+                s_count, s_count * sch.k * nbytes, s_count, s_count * back), surv
+            assert per.dtype == np.uint8
+            assert np.array_equal(batch, per.reshape(batch.shape)), (scheme, surv)
+            assert np.array_equal(batch.reshape(s_count, sch.k, nbytes), data), surv
+            tested += 1
     assert tested > 1
 
 
@@ -169,6 +183,42 @@ def test_oob_meta_batch_bit_identical(scheme, n_drives):
         )
         assert np.array_equal(d_lba[s], dl) and np.array_equal(d_ts[s], dt)
     assert np.array_equal(d_lba, lbas) and np.array_equal(d_ts, ts)
+
+
+@pytest.mark.parametrize("scheme", ["raid5", "raid4"])
+def test_single_parity_decode_np_one_issue_span_one_xor(scheme, monkeypatch):
+    """Each single-parity ``decode_np`` opens one ``codec:issue`` span keyed
+    ``("decode", ((k, n),))`` and calls ``ops.xor_parity`` once, through the
+    module attribute, on the (k, n) survivors."""
+    codec = _codec(scheme, 4)
+    k, nbytes = codec.scheme.k, 512
+    data = np.random.default_rng(7).integers(0, 256, (k, nbytes), dtype=np.uint8)
+    code = np.concatenate([data, codec.encode_np(data)])
+    # every lost data role, the k survivors in every order
+    orders = [
+        order
+        for lost in range(k)
+        for order in itertools.permutations([r for r in range(k + 1) if r != lost])
+    ]
+    calls = []
+    xor = ops.xor_parity
+
+    def counting(*args, **kw):
+        calls.append(tuple(tuple(a.shape) for a in args if hasattr(a, "shape")))
+        return xor(*args, **kw)
+
+    monkeypatch.setattr(ops, "xor_parity", counting)
+    rec = HostSpans(annotate=False).install()
+    try:
+        for order in orders:
+            codec.decode_np(code[list(order)], order)
+        snap = rec.snapshot()
+    finally:
+        rec.uninstall()
+    shapes = ((k, nbytes // 4),)
+    assert snap["spans"]["codec:issue"]["count"] == len(orders)
+    assert snap["dispatches"] == {("decode", shapes): len(orders)}
+    assert calls == [shapes] * len(orders)
 
 
 # ---------------------------------------------------------------- L2P level
