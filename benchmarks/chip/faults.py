@@ -21,6 +21,13 @@ back afterwards):
 
 The exchange between chips does not exist in a one-chip cell.
 
+In the rebuild cell (``runners/rebuild.py``) the fault stands from the
+prefill on: ``parity_zero``, ``half_batch`` and ``answer_flip`` leave
+survivors from which the replaced drives' blocks do not decode, and a
+flipped decode writes a wrong block; ``media_unchanged`` and ``crc_zero``
+leave the replaced drives without the blocks or their checksums.  It
+acknowledges no write, so ``ack_early`` has nothing to break there.
+
 Run the control at a cell's own size on the chip with
 
     python3 benchmarks/chip/faults.py --workload <cell> --seed <n> \

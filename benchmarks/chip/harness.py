@@ -1,22 +1,38 @@
-"""One run of one cell: build, prefill, fail drives, warm up, measure, check.
+"""One run of one cell: the frame that every kind of run shares.
 
 ``run_cell`` does the work and returns the result object that ``run.py``
 prints; it takes the cell as data (``specs.Cell``), so the tests drive it
 at a small geometry on the CPU.  In order:
 
-1. build the array with ``HandlerPipeline.build_timed`` and a
-   ``BlockDeviceService`` in front of it;
+1. look up the device and its peaks; build the array with
+   ``HandlerPipeline.build_timed`` from the configuration's keys;
 2. prefill the volume through ``precondition`` with bytes drawn from the
    seed, recorded in the plain reference as they are handed over;
-3. fail the traffic's drives;
-4. warm up the codec shapes this cell's traffic dispatches and no others;
-5. run the closed loop for ``seconds`` of wall time (with ``trace``: the
-   layer spans and the profiler on), drain, read the device's memory peak;
-6. check against the reference: every read the window answered; for write
-   cells, at a seeded eighth of the acknowledgements, that the write is on
-   the media already, then after the drain a seeded sample of the volume
-   read back healthy and again with as many drives failed as the
-   configuration survives, and the checksums stored beside the blocks.
+3. fail the traffic's ``failed_drives``;
+4. the runner's ``prepare``: warm up what its window dispatches and no
+   more, and set up the window's work;
+5. run the window for ``seconds`` of wall time (with ``trace``: the
+   benchmark's wrappers, the program's span recorder and the profiler on),
+   read the device's memory peak and the metrics;
+6. check: the frame's own numbers (no unit of work failed, no compile in
+   the window, every codec compiled), then the runner's, against the
+   reference.
+
+A runner (``runners/<name>.py``, named by the traffic's ``runner``) is one
+kind of run.  It provides
+
+* ``CLIENT``: ``(layer, owner, attrs)`` points of its own client code that
+  the traced window wraps (``layers.LayerSpans.install``), so that the
+  service's time leaves them out;
+* ``prepare(b, ref, cell, rngs)``: the warm-up, then the window's work: an
+  object with ``run(seconds)``, which measures and calls ``on_close`` (set
+  by the frame) once, as the window closes; ``samples``, one
+  ``loadgen.Sample`` per unit of work (``attempted`` and ``failed`` count
+  them); ``t_start`` and ``t_close``, the window's bounds; and
+  ``in_window(op)``, the samples of the traffic's ``op`` that the metric
+  readers count (``Window.mib``);
+* ``check(b, ref, work, cell, rng, stats_window)``: its numbers compared,
+  ``{name: (value, limit, rule)}``.
 
 Every time is host wall time (``time.perf_counter``) or device time from
 the profiler trace; the drive model's virtual clock only orders events.
@@ -36,15 +52,11 @@ import numpy as np
 
 import layers
 import tracereduce
-from loadgen import AddressStream, ClosedLoop, PayloadSource
 from reference import BlockReference, crc32c_rows
-from specs import HERE, Cell
+from specs import HERE, Cell, config_parts
 
 MiB = 1 << 20
-TENANT = "bench"
 PREFILL_CHUNK_BLOCKS = 1024
-WARM_READS = 512
-ACK_EVERY = 8
 
 
 class NoChip(RuntimeError):
@@ -62,47 +74,50 @@ def _sub_seeds(seed: int) -> dict:
     return {n: np.random.default_rng(k) for n, k in zip(names, kids)}
 
 
-def zones_per_drive(config: dict, k: int, volume_blocks: int) -> int:
+def zones_per_drive(cfg, zns, k: int, volume_blocks: int) -> int:
+    """The volume and half again for GC headroom, in segments of
+    ``chunk_blocks`` chunks, then two zones for each segment open at once
+    (one; ``n_small + n_large`` with hybrid data management): its own, and
+    the GC watermark's, which also takes the segment a drive failure opens
+    in its place at survivor width."""
     from repro.core.segment import solve_stripes_per_segment
     stripes, _ = solve_stripes_per_segment(
-        config["zone_cap_blocks"], config["chunk_blocks"], config["block_bytes"])
-    return math.ceil(1.5 * volume_blocks / (k * stripes)) + 2
+        zns.zone_cap_blocks, cfg.chunk_blocks, zns.block_bytes)
+    open_segments = cfg.n_small + cfg.n_large if cfg.hybrid else 1
+    return math.ceil(1.5 * volume_blocks / (k * stripes)) + 2 * open_segments
+
+
+def array_configs(config: dict, traffic: dict):
+    """``(ZapRaidConfig, ZnsConfig)`` of a cell: the configuration's keys as
+    they stand, the volume from the traffic, the zones by
+    :func:`zones_per_drive`."""
+    from repro.core.array import ZapRaidConfig
+    from repro.core.raid import make_scheme
+    from repro.core.zns import ZnsConfig
+
+    array_keys, zns_keys = config_parts(config)
+    zns = ZnsConfig(**zns_keys)
+    volume_blocks = traffic["volume_mib"] * MiB // zns.block_bytes
+    cfg = ZapRaidConfig(logical_blocks=volume_blocks, **array_keys)
+    k = make_scheme(cfg.scheme, cfg.n_drives).k
+    zns.n_zones = zones_per_drive(cfg, zns, k, volume_blocks)
+    return cfg, zns
 
 
 @dataclasses.dataclass
 class Built:
     pipe: object
-    svc: object
     arr: object
     volume_blocks: int
     n_zones: int
 
 
 def build(config: dict, traffic: dict, drive_seed: int) -> Built:
-    from repro.core.array import ZapRaidConfig
     from repro.core.handlers import HandlerPipeline
-    from repro.core.raid import make_scheme
-    from repro.core.zns import ZnsConfig
-    from repro.service import BlockDeviceService, QosClass
 
-    bb = config["block_bytes"]
-    volume_blocks = traffic["volume_mib"] * MiB // bb
-    k = make_scheme(config["scheme"], config["n_drives"]).k
-    n_zones = zones_per_drive(config, k, volume_blocks)
-    cfg = ZapRaidConfig(
-        scheme=config["scheme"], n_drives=config["n_drives"],
-        group_size=config["group_size"], chunk_blocks=config["chunk_blocks"],
-        logical_blocks=volume_blocks,
-        gc_free_segments_low=config["gc_free_segments_low"],
-        batched=config["batched"], verify_reads=config["verify_reads"],
-        append_order=config["append_order"],
-    )
-    zns = ZnsConfig(n_zones=n_zones, zone_cap_blocks=config["zone_cap_blocks"],
-                    block_bytes=bb, max_open_zones=config["max_open_zones"])
+    cfg, zns = array_configs(config, traffic)
     pipe = HandlerPipeline.build_timed(cfg, zns, seed=drive_seed)
-    svc = BlockDeviceService(pipe, max_inflight=traffic["qd"], policy="fifo")
-    svc.register(TENANT, QosClass(TENANT, queue_cap=1 << 30))
-    return Built(pipe, svc, pipe.array, volume_blocks, n_zones)
+    return Built(pipe, pipe.array, cfg.logical_blocks, zns.n_zones)
 
 
 def codec_modes(arr) -> list[tuple[bool, bool]]:
@@ -121,32 +136,6 @@ def prefill(b: Built, ref: BlockReference, traffic: dict, rng) -> None:
     )
 
 
-def warm_up(b: Built, traffic: dict, rng) -> None:
-    """Compile, or load from the cache, what the window will dispatch.
-
-    Writes: the group encode of data and of metadata at every power-of-two
-    stripe count up to G (a flush or a segment's end commits a partial
-    group).  Reads: degraded reads of seeded LBAs through the array, which
-    reach every parity rotation, so every survivor set's decode."""
-    arr = b.arr
-    if traffic["op"] == "write":
-        codec = arr.codec
-        k, c = codec.scheme.k, arr.cfg.chunk_blocks
-        lanes = c * arr.zns_cfg.block_bytes // 4
-        meta_lanes = 16 * c // 4   # a (lba, ts) u64 pair per block
-        s = 1
-        while s <= arr.cfg.group_size:
-            for n in (lanes, meta_lanes):
-                codec.materialize(codec.encode_batch_async(
-                    np.zeros((s, k, n), np.int32)))
-            s *= 2
-    else:
-        for lba in rng.integers(0, b.volume_blocks, WARM_READS):
-            arr.read(int(lba), 1)
-        for d in arr.drives:
-            d.reset_timing()   # the warm-up's reads book no device time
-
-
 @dataclasses.dataclass
 class Window:
     """What a run measured, for the metric readers (``metrics/*.py``)."""
@@ -154,17 +143,19 @@ class Window:
     cell: Cell
     setup_s: float
     window_s: float
-    loop: ClosedLoop
+    loop: object                       # the runner's work (see the module doc)
     block_bytes: int
     stats0: dict
     stats1: dict
     spans: Optional[dict] = None       # layers.LayerSpans.snapshot()
+    program: Optional[dict] = None     # repro.obs.HostSpans.snapshot()
     trace: Optional[dict] = None       # device events and window bounds
     peaks: Optional[dict] = None
 
     def mib(self, op: str) -> float:
-        """User MiB acknowledged (writes) or returned (reads) by the
-        deadline."""
+        """MiB of the samples of ``op`` the window counts: user MiB
+        acknowledged (writes) or returned (reads) by the deadline, MiB
+        restored (rebuild passes)."""
         return sum(s.n_blocks for s in self.loop.in_window(op)) \
             * self.block_bytes / MiB
 
@@ -202,6 +193,27 @@ class Window:
         if seconds is None or mib <= 0:
             return None
         return seconds * 1e3 / mib
+
+    # -- the program's own spans (repro.obs.hostspans) -----------------------
+
+    def program_self_s(self, *names: str,
+                       layer: Optional[str] = None) -> Optional[float]:
+        """Summed self seconds of the named program spans, or of every span
+        of ``layer``; 0.0 for spans that never opened."""
+        if self.program is None:
+            return None
+        return sum(s["self_s"] for name, s in self.program["spans"].items()
+                   if name in names or name.split(":")[0] == layer)
+
+    def program_count(self, name: str) -> Optional[int]:
+        if self.program is None:
+            return None
+        return self.program["spans"].get(name, {}).get("count", 0)
+
+    def program_per_mib_ms(self, op: str, *names: str,
+                           layer: Optional[str] = None) -> Optional[float]:
+        """Self milliseconds of the program spans per MiB of ``op``."""
+        return self.per_mib_ms(self.program_self_s(*names, layer=layer), op)
 
     def device_events(self) -> Optional[list]:
         if not self.trace or not self.trace["device"]:
@@ -276,24 +288,6 @@ def media(arr, drives, zones, offs, where=None):
     return blocks, crcs
 
 
-def unpersisted_blocks(arr, writes: list, payloads: PayloadSource,
-                       k: int) -> int:
-    """Blocks of the ``k``-th write that are not on the media.  Each must
-    read, where the L2P puts it, as its own payload or as a later payload
-    to the same LBA (``writes[i]`` carries payload ``i``)."""
-    n = writes[k].n_blocks
-    lbas = writes[k].lba + np.arange(n)
-    mapped, drives, zones, offs = locate(arr, lbas)
-    got, _ = media(arr, drives, zones, offs, mapped)
-    gi, gj = payloads.stamps(got)
-    ok = mapped & (gi >= k) & (gi < len(writes)) & (gj >= 0) & (gj < n)
-    idx = np.flatnonzero(ok)
-    start = np.array([writes[g].lba for g in gi[idx]], np.int64)
-    ok[idx] = (start + gj[idx] == lbas[idx]) & np.all(
-        got[idx] == payloads.blocks(gi[idx], gj[idx], n), axis=1)
-    return int((~ok).sum())
-
-
 def crc_mismatches(arr, ref: BlockReference, lbas: np.ndarray, rng,
                    n_sample: int) -> int:
     """Blocks whose stored CRC32C is wrong: each sampled LBA's block
@@ -325,41 +319,6 @@ def read_back(b: Built, ref: BlockReference, lbas: np.ndarray, n_blocks: int):
     return bad
 
 
-def check(b: Built, ref: BlockReference, loop: ClosedLoop, cell: Cell,
-          rng, stats_window: dict, tallies: dict) -> dict:
-    """Every number compared, each with its limit and rule."""
-    traffic, config = cell.traffic, cell.config
-    checks = {}
-    failed = sum(1 for s in loop.samples if not s.ok)
-    checks["failed_requests"] = (failed, 0, "<=")
-    checks["compiles_in_window"] = (tallies["compiles"], 0, "<=")
-    if traffic["op"] == "read":
-        bad = sum(ref.mismatches(s.lba, s.n_blocks, s.result)
-                  for s in loop.samples if s.ok)
-        checks["read_mismatched_blocks"] = (bad, 0, "<=")
-        checks["window_degraded_reads"] = (stats_window["degraded_reads"], 1, ">=")
-        return checks
-    # writes: a seeded sample of the extents the window acknowledged, read
-    # back healthy, then with the configuration's drive losses
-    n = traffic["request_blocks"]
-    acked = np.unique([s.lba for s in loop.samples if s.ok])
-    want = max(1, traffic["check_mib"] * MiB // (n * config["block_bytes"]))
-    lbas = rng.choice(acked, size=min(want, acked.size), replace=False)
-    checks["unpersisted_acked_blocks"] = (tallies["unpersisted"], 0, "<=")
-    checks["readback_mismatched_blocks"] = (read_back(b, ref, lbas, n), 0, "<=")
-    blocks = (lbas[:, None] + np.arange(n)).ravel()
-    checks["crc_mismatched_blocks"] = (
-        crc_mismatches(b.arr, ref, blocks, rng, blocks.size), 0, "<=")
-    losses = config["guarantee"]["drive_losses_survived"]
-    failed_drives = sorted(rng.choice(config["n_drives"], losses, replace=False))
-    d0 = b.arr.stats.degraded_reads
-    for d in failed_drives:
-        b.arr.fail_drive(int(d))
-    checks["degraded_mismatched_blocks"] = (read_back(b, ref, lbas, n), 0, "<=")
-    checks["degraded_blocks_decoded"] = (b.arr.stats.degraded_reads - d0, 1, ">=")
-    return checks
-
-
 def passes(value, limit, rule) -> bool:
     return value <= limit if rule == "<=" else value >= limit
 
@@ -389,6 +348,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              t_process: float, require_tpu: bool = True) -> dict:
     """Run one cell; returns the result object (the contract's last line)."""
     import jax
+    from repro.obs.hostspans import HostSpans
 
     devs = jax.devices()
     if require_tpu and (devs[0].platform != "tpu" or len(devs) < cell.chips):
@@ -398,41 +358,34 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     clock = layers.CompileClock()
     jax.monitoring.register_event_duration_secs_listener(clock)
     rngs = _sub_seeds(seed)
-    config, traffic = cell.config, cell.traffic
-    bb = config["block_bytes"]
+    traffic, runner = cell.traffic, cell.runner
 
-    b = build(config, traffic, int(rngs["drives"].integers(1 << 31)))
+    b = build(cell.config, traffic, int(rngs["drives"].integers(1 << 31)))
+    cfg, zns = b.arr.cfg, b.arr.zns_cfg
+    bb = zns.block_bytes
     if require_tpu and any(m != (True, False) for m in codec_modes(b.arr)):
         raise HarnessError(f"codec resolved to {codec_modes(b.arr)}, not the "
                            "compiled Pallas kernels (use_pallas=True, "
                            "interpret=False)")
-    log(f"[{cell.name}] {config['scheme']} {config['n_drives']} drives x "
-        f"{b.n_zones} zones of {config['zone_cap_blocks']} blocks; volume "
+    log(f"[{cell.name}] {cfg.scheme} {cfg.n_drives} drives x "
+        f"{b.n_zones} zones of {zns.zone_cap_blocks} blocks; volume "
         f"{b.volume_blocks} blocks; codec {codec_modes(b.arr)}")
     ref = BlockReference(b.volume_blocks, bb)
     t = time.perf_counter()
     prefill(b, ref, traffic, rngs["prefill"])
     log(f"[{cell.name}] prefill {traffic['prefill_mib']} MiB: host wall "
         f"{time.perf_counter() - t:.3f} s")
-    for d in traffic["failed_drives"]:
+    for d in traffic.get("failed_drives", ()):
         b.arr.fail_drive(int(d))
     t = time.perf_counter()
-    warm_up(b, traffic, rngs["traffic"])
+    work = runner.prepare(b, ref, cell, rngs)
     log(f"[{cell.name}] warm-up: host wall {time.perf_counter() - t:.3f} s")
 
-    stream = AddressStream(traffic["address"], b.volume_blocks,
-                           traffic["request_blocks"], rngs["traffic"])
-    payloads = PayloadSource(bb, rngs["payload"]) if traffic["op"] == "write" else None
-    loop = ClosedLoop(b.svc, TENANT, traffic["op"], traffic["request_blocks"],
-                      traffic["qd"], stream, ref, payloads)
-    spans = window_span = None
+    spans = program = window_span = None
     trace_dir = None
     if trace:
-        spans = layers.LayerSpans().install(extra=(
-            ("client", PayloadSource, ("make",)),
-            ("client", BlockReference, ("write",)),
-            ("client", sys.modules[__name__], ("unpersisted_blocks",)),
-        ))
+        spans = layers.LayerSpans().install(extra=runner.CLIENT)
+        program = HostSpans().install()
         trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -448,51 +401,44 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         if spans is not None:
             spans.active = False
             captured["spans"] = spans.snapshot()
+            # spans still open (the event loop's root among them) count up
+            # to this moment
+            captured["program"] = program.snapshot()
             window_span.__exit__(None, None, None)
             jax.profiler.stop_trace()
 
-    loop.on_close = on_close
-    unpersisted = [0]
-    if payloads is not None:
-        # a write is acknowledged only once it has persisted: at every
-        # ACK_EVERY-th acknowledgement, from a seeded phase, its blocks must
-        # be on the media already
-        phase = int(rngs["check"].integers(ACK_EVERY))
-
-        def on_ack(k: int) -> None:
-            if k % ACK_EVERY == phase:
-                unpersisted[0] += unpersisted_blocks(b.arr, loop.samples,
-                                                     payloads, k)
-
-        loop.on_ack = on_ack
+    work.on_close = on_close
     stats0 = _stats(b.arr)
     compiles0, compile_s0 = clock.count, clock.seconds
     setup_s = time.perf_counter() - t_process
-    if spans is not None:
-        window_span.__enter__()
-        spans.active = True
-    loop.run(seconds)
-    window = Window(cell=cell, setup_s=setup_s, window_s=loop.t_close - loop.t_start,
-                    loop=loop, block_bytes=bb, stats0=stats0,
+    try:
+        if spans is not None:
+            window_span.__enter__()
+            spans.active = True
+        work.run(seconds)
+    finally:
+        if spans is not None:
+            spans.uninstall()
+            program.uninstall()
+    window = Window(cell=cell, setup_s=setup_s, window_s=work.t_close - work.t_start,
+                    loop=work, block_bytes=bb, stats0=stats0,
                     stats1=captured["stats1"], spans=captured.get("spans"),
-                    peaks=peaks)
-    if spans is not None:
-        spans.uninstall()
+                    program=captured.get("program"), peaks=peaks)
+    if trace:
         window.trace = _read_trace(trace_dir, window.window_s)
         shutil.rmtree(trace_dir, ignore_errors=True)
     device = _device_info(jax)
-    tallies = {"compiles": captured["compiles"] - compiles0,
-                "unpersisted": unpersisted[0]}
+    compiles = captured["compiles"] - compiles0
+    op = traffic["op"]
     log(f"[{cell.name}] set-up {setup_s:.3f} s, of which compile "
         f"{compile_s0:.3f} s over {compiles0} compiles; compiles in the "
-        f"window: {tallies['compiles']}")
-    log(f"[{cell.name}] window {window.window_s:.3f} s: {len(loop.samples)} "
-        f"requests issued, {len(loop.in_window(traffic['op']))} completed by "
-        f"the deadline")
+        f"window: {compiles}")
+    log(f"[{cell.name}] window {window.window_s:.3f} s: {len(work.samples)} "
+        f"{op} issued, {len(work.in_window(op))} counted")
 
-    result = {"correct": False, "attempted": len(loop.samples),
-              "failed": sum(1 for s in loop.samples if not s.ok),
-              "metrics": {}, "device": device}
+    failed = sum(1 for s in work.samples if not s.ok)
+    result = {"correct": False, "attempted": len(work.samples),
+              "failed": failed, "metrics": {}, "device": device}
     for name, mod, entry in cell.metrics:
         value = mod.read(window)
         if value is not None:
@@ -505,7 +451,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     stats_window = {k: window.stats1[k] - window.stats0[k] for k in stats0}
     t = time.perf_counter()
-    checks = check(b, ref, loop, cell, rngs["check"], stats_window, tallies)
+    checks = {"failed_requests": (failed, 0, "<="),
+              "compiles_in_window": (compiles, 0, "<=")}
+    checks.update(runner.check(b, ref, work, cell, rngs["check"], stats_window))
     log(f"[{cell.name}] check: host wall {time.perf_counter() - t:.3f} s")
     if require_tpu:
         bad = sum(m != (True, False) for m in codec_modes(b.arr))

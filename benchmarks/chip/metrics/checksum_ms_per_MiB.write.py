@@ -1,6 +1,4 @@
 """Self time of the program's checksum:crc32c spans (every integrity.checksum.crc32c_many call) per user MiB written."""
-import programspans
-
 LAYER = "checksum"
 UNIT = "ms/MiB"
 SOURCE = "program_span"
@@ -8,4 +6,4 @@ MOVES = "write_MiBps"
 
 
 def read(w):
-    return programspans.per_mib_ms(w, "write", "checksum:crc32c")
+    return w.program_per_mib_ms("write", "checksum:crc32c")

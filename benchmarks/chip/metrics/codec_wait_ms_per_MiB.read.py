@@ -1,6 +1,4 @@
 """Self time of the program's codec:wait spans (StripeCodec.materialize blocked on the device's decode result, before the copy back) per user MiB read."""
-import programspans
-
 LAYER = "codec"
 UNIT = "ms/MiB"
 SOURCE = "program_span"
@@ -8,4 +6,4 @@ MOVES = "read_MiBps"
 
 
 def read(w):
-    return programspans.per_mib_ms(w, "read", "codec:wait")
+    return w.program_per_mib_ms("read", "codec:wait")

@@ -1,6 +1,4 @@
 """Self time of the program's codec:wait spans (StripeCodec.materialize blocked on the device's encode result, before the copy back) per user MiB written."""
-import programspans
-
 LAYER = "codec"
 UNIT = "ms/MiB"
 SOURCE = "program_span"
@@ -8,4 +6,4 @@ MOVES = "write_MiBps"
 
 
 def read(w):
-    return programspans.per_mib_ms(w, "write", "codec:wait")
+    return w.program_per_mib_ms("write", "codec:wait")

@@ -1,6 +1,4 @@
 """Self time of the program's codec:issue spans (host time issuing the decode programs, the eager stack and index ops included) per user MiB read."""
-import programspans
-
 LAYER = "codec"
 UNIT = "ms/MiB"
 SOURCE = "program_span"
@@ -8,4 +6,4 @@ MOVES = "read_MiBps"
 
 
 def read(w):
-    return programspans.per_mib_ms(w, "read", "codec:issue")
+    return w.program_per_mib_ms("read", "codec:issue")

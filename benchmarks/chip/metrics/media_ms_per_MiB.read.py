@@ -1,6 +1,4 @@
 """Self time of the program's media:read spans (the drive model's reads of the block and of a degraded read's survivors) per user MiB read."""
-import programspans
-
 LAYER = "media"
 UNIT = "ms/MiB"
 SOURCE = "program_span"
@@ -8,4 +6,4 @@ MOVES = "read_MiBps"
 
 
 def read(w):
-    return programspans.per_mib_ms(w, "read", "media:read")
+    return w.program_per_mib_ms("read", "media:read")

@@ -1,6 +1,4 @@
 """Self time of the program's media:* spans (the drive model: media:append, media:read and the TimedDrive booking, media:book) per user MiB written."""
-import programspans
-
 LAYER = "media"
 UNIT = "ms/MiB"
 SOURCE = "program_span"
@@ -8,4 +6,4 @@ MOVES = "write_MiBps"
 
 
 def read(w):
-    return programspans.per_mib_ms(w, "write", layer="media")
+    return w.program_per_mib_ms("write", layer="media")

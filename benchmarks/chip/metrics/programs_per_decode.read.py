@@ -1,5 +1,4 @@
 """Device programs per decode: the module events (XLA Modules line) that start inside the traced window, over the program's codec:issue spans in the window (a read cell issues decodes only)."""
-import programspans
 import tracereduce
 
 LAYER = "codec"
@@ -10,7 +9,7 @@ MOVES = "read_MiBps"
 
 def read(w):
     events = w.device_events()
-    issued = programspans.count(w, "codec:issue")
+    issued = w.program_count("codec:issue")
     if events is None or not issued:
         return None
     t0, t1 = w.trace["t0_ns"], w.trace["t1_ns"]

@@ -1,6 +1,4 @@
 """Self time of every service:* span of the program (the event loop, arrivals, dispatches, completions and the pipeline's handlers; calls back into the client excluded) per user MiB read."""
-import programspans
-
 LAYER = "service & pipeline"
 UNIT = "ms/MiB"
 SOURCE = "program_span"
@@ -8,4 +6,4 @@ MOVES = "read_MiBps"
 
 
 def read(w):
-    return programspans.per_mib_ms(w, "read", layer="service")
+    return w.program_per_mib_ms("read", layer="service")

@@ -1,6 +1,4 @@
 """Self time of the program's codec:h2d and codec:d2h spans (the survivors' copy to the device and the decoded chunks' copy back) per user MiB read."""
-import programspans
-
 LAYER = "codec"
 UNIT = "ms/MiB"
 SOURCE = "program_span"
@@ -8,4 +6,4 @@ MOVES = "read_MiBps"
 
 
 def read(w):
-    return programspans.per_mib_ms(w, "read", "codec:h2d", "codec:d2h")
+    return w.program_per_mib_ms("read", "codec:h2d", "codec:d2h")

@@ -2,11 +2,12 @@
 planted fault, and passes the whole path.
 
 Each case drives a whole run of a cell at a small size on the CPU --
-prefill, drive failures, warm-up, the closed-loop window, the check --
-with the timed path broken underneath (``faults.py``), skipping only the
-harness's look for a chip.  ``parity_zero`` is the control: acknowledged
-writes without their parity, which breaks the configurations' guarantee
-that every acknowledged block survives their drive losses.
+prefill, drive failures, warm-up, the window (the closed loop, or the
+rebuild passes), the check -- with the timed path broken underneath
+(``faults.py``), skipping only the harness's look for a chip.
+``parity_zero`` is the control: acknowledged writes without their parity,
+which breaks the configurations' guarantee that every acknowledged block
+survives their drive losses.
 """
 import pytest
 
@@ -15,7 +16,7 @@ from tinycell import run_tiny
 import faults
 
 CELLS = ("raid5.write.seq128k", "raid5.read.degraded4k",
-         "raid6.write.seq128k", "raid6.read.degraded4k")
+         "raid6.write.seq128k", "raid6.read.degraded4k", "raid6.rebuild.2f")
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -32,6 +33,10 @@ CASES = [(cell, fault) for fault in sorted(faults.FAULTS)
          for cell in ("raid5.write.seq128k", "raid6.write.seq128k",
                       "raid6.read.degraded4k")
          if fault not in WRITE_ONLY or ".write." in cell]
+# the rebuild writes every block of the replaced drives, checksums
+# included, but acknowledges nothing
+CASES += [("raid6.rebuild.2f", fault) for fault in sorted(faults.FAULTS)
+          if fault != "ack_early"]
 
 
 @pytest.mark.parametrize("cell,fault", CASES)
@@ -53,13 +58,17 @@ def test_write_fault_fails_its_own_number(fault, number):
 
 def test_control_is_caught_on_every_cell_kind():
     # the control is what a later change would be tempted by: it fails the
-    # degraded comparison of both a write and a read cell of RAID-5 too
-    for cell in ("raid5.write.seq128k", "raid5.read.degraded4k"):
+    # degraded comparison of both a write and a read cell of RAID-5 too, and
+    # both comparisons of the rebuild
+    for cell in ("raid5.write.seq128k", "raid5.read.degraded4k",
+                 "raid6.rebuild.2f"):
         r = run_tiny(cell, fault=faults.CONTROL)
         assert not r["correct"], r["checks"]
         bad = {k: v for k, v in r["checks"].items()
                if v["rule"] == "<=" and v["value"] > v["limit"]}
-        assert set(bad) <= {"degraded_mismatched_blocks", "read_mismatched_blocks"}
+        assert bad and set(bad) <= {
+            "degraded_mismatched_blocks", "read_mismatched_blocks",
+            "rebuilt_mismatched_blocks", "rebuilt_readback_mismatched_blocks"}
 
 
 def test_faults_are_removed_afterwards():

@@ -10,7 +10,7 @@ import sys
 import numpy as np
 import pytest
 
-from tinycell import CHIP, ROOT, run_tiny
+from tinycell import CHIP, ROOT, TINY_MOST, TINY_TRAFFIC, run_tiny
 
 import harness
 import reference
@@ -48,7 +48,8 @@ def test_every_cell_loads_by_name(cell):
     for trace in (False, True):
         c = specs.load_cell(cell, trace=trace)
         assert c.config["name"] == w["config"]
-        assert c.traffic["op"] in ("read", "write")
+        assert c.traffic["op"] in ("read", "write", "rebuild")
+        assert callable(c.runner.prepare) and callable(c.runner.check)
         assert c.metrics, "every cell reports metrics in both modes"
     names = {n for n, _, _ in specs.metrics_for(BENCH, cell, False)}
     assert "setup_s" in names and len(names) >= 2
@@ -108,11 +109,12 @@ def test_every_cell_of_a_layer_metric_reports_what_it_moves(metric):
     moves = e2e[metric["moves"]]
     for cell in metric.get("workloads", CELL_NAMES):
         assert cell in moves.get("workloads", CELL_NAMES), (metric["name"], cell)
-    # a .write metric only in write cells, a .read metric only in read cells
+    # a .write metric only in write cells, a .read metric only in read
+    # cells, a .rebuild metric only in rebuild cells
     op = metric["name"].rsplit(".", 1)[-1]
     for cell in metric["workloads"]:
         traffic = specs.load_cell(cell, trace=True).traffic
-        assert op not in ("read", "write") or traffic["op"] == op
+        assert op not in ("read", "write", "rebuild") or traffic["op"] == op
 
 
 # -- the closed loop and the metric arithmetic ------------------------------
@@ -279,3 +281,155 @@ def test_cli_refuses_a_checkout_without_the_program(tmp_path):
     p = _child(tmp_path, "--workload", "raid5.write.seq128k", "--seed", "1",
                "--seconds", "1", "--trace", "0")
     assert p.returncode != 0 and p.stdout == ""
+
+
+# -- configurations pass through whole ---------------------------------------
+
+
+def _old_build(config, traffic):
+    """The array and drive configuration as the harness built them from a
+    fixed list of keys, before the configuration passed through whole."""
+    from repro.core.array import ZapRaidConfig
+    from repro.core.raid import make_scheme
+    from repro.core.segment import solve_stripes_per_segment
+    from repro.core.zns import ZnsConfig
+
+    bb = config["block_bytes"]
+    volume_blocks = traffic["volume_mib"] * harness.MiB // bb
+    k = make_scheme(config["scheme"], config["n_drives"]).k
+    stripes, _ = solve_stripes_per_segment(
+        config["zone_cap_blocks"], config["chunk_blocks"], bb)
+    n_zones = -(-3 * volume_blocks // (2 * k * stripes)) + 2
+    cfg = ZapRaidConfig(
+        scheme=config["scheme"], n_drives=config["n_drives"],
+        group_size=config["group_size"], chunk_blocks=config["chunk_blocks"],
+        logical_blocks=volume_blocks,
+        gc_free_segments_low=config["gc_free_segments_low"],
+        batched=config["batched"], verify_reads=config["verify_reads"],
+        append_order=config["append_order"],
+    )
+    zns = ZnsConfig(n_zones=n_zones, zone_cap_blocks=config["zone_cap_blocks"],
+                    block_bytes=bb, max_open_zones=config["max_open_zones"])
+    return cfg, zns
+
+
+@pytest.mark.parametrize("cell", CELL_NAMES)
+def test_configs_build_the_arrays_they_built(cell):
+    c = specs.load_cell(cell, trace=False)
+    cfg, zns = harness.array_configs(c.config, c.traffic)
+    assert (cfg, zns) == _old_build(c.config, c.traffic)
+    assert zns.n_zones == 3
+
+
+def test_an_unknown_config_key_is_refused(tmp_path, monkeypatch):
+    config = specs.load_json("configs", "raid5-3p1")
+    assert specs.config_parts(config)[1] == {
+        "zone_cap_blocks": 275712, "block_bytes": 4096, "max_open_zones": 14}
+    for bad in ("hybird", "logical_blocks", "n_zones"):
+        with pytest.raises(ValueError, match=bad):
+            specs.config_parts(dict(config, **{bad: 1}))
+    # refused as the cell loads, before anything is built
+    monkeypatch.setattr(specs, "load_json", lambda kind, name: (
+        dict(config, hybird=True) if kind == "configs" else
+        json.loads((CHIP / kind / f"{name}.json").read_text())))
+    with pytest.raises(ValueError, match="hybird"):
+        specs.load_cell("raid5.write.seq128k", trace=False)
+
+
+def test_a_hybrid_configuration_is_a_file_alone(monkeypatch):
+    from repro.core.segment import SegmentClass
+
+    load = specs.load_json
+
+    def hybrid(kind, name):
+        data = load(kind, name)
+        if kind == "configs":
+            # GC keeps a zone free for each open segment, which a drive
+            # failure reopens at survivor width
+            data.update(hybrid=True, n_small=1, n_large=1,
+                        small_chunk_blocks=1, large_chunk_blocks=4,
+                        gc_free_segments_low=2)
+        return data
+
+    monkeypatch.setattr(specs, "load_json", hybrid)
+    built = []
+    monkeypatch.setattr(harness, "build", lambda *a, _b=harness.build: (
+        built.append(_b(*a)) or built[-1]))
+    r = run_tiny("raid5.write.seq128k", seconds=1.0)
+    assert r["correct"], r["checks"]
+    arr = built[0].arr
+    assert arr.cfg.hybrid and arr.cfg.n_large == 1 and arr.large_ids
+    classes = {rec.info.seg_class for rec in arr.segments.values()}
+    assert int(SegmentClass.LARGE) in classes
+    # the window's requests are large enough to go to the large segments
+    assert TINY_MOST["request_blocks"] >= arr.cfg.large_chunk_blocks
+
+
+# -- the rebuild cell ----------------------------------------------------------
+
+REBUILD = "raid6.rebuild.2f"
+
+
+def test_rebuild_cell_is_correct_and_reports_its_rate():
+    r = run_tiny(REBUILD)
+    assert r["correct"], r["checks"]
+    assert set(r["metrics"]) == {"rebuild_MiBps", "setup_s"}
+    assert r["metrics"]["rebuild_MiBps"]["value"] > 0
+    checks = r["checks"]
+    assert checks["rebuild_passes"]["value"] == r["attempted"] >= 1
+    # each pass leaves both replaced drives holding the prefilled zone: a
+    # header and one chunk a stripe of two data chunks
+    volume_blocks = TINY_TRAFFIC["volume_mib"] * harness.MiB // 4096
+    per_pass = checks["rebuilt_blocks"]["value"] / r["attempted"]
+    assert per_pass == 2 * (1 + volume_blocks // 2)
+
+
+def test_rebuild_cell_traced_reports_its_layer_metrics():
+    r = run_tiny(REBUILD, trace=True)
+    assert r["correct"], r["checks"]
+    want = {m["name"] for m in BENCH["per_layer"]
+            if REBUILD in m.get("workloads", []) and m["source"] == "program_span"}
+    assert want and set(r["metrics"]) == want
+    assert all(m["value"] > 0 for m in r["metrics"].values())
+
+
+# -- the four block cells read as before --------------------------------------
+
+BLOCK_E2E = {"write": {"write_MiBps", "setup_s"},
+             "read": {"read_MiBps", "read_p99_ms", "setup_s"}}
+BLOCK_TRACED = {
+    "write": {"service_ms_per_MiB.write", "array_ms_per_MiB.write",
+              "write_amp.write", "crc_ms_per_MiB.write", "codec_ms_per_MiB.write",
+              "stage_ms_per_MiB.write", "bookkeep_ms_per_MiB.write",
+              "media_ms_per_MiB.write", "checksum_ms_per_MiB.write",
+              "codec_wait_ms_per_MiB.write", "service_self_ms_per_MiB.write"},
+    "read": {"service_ms_per_MiB.read", "array_ms_per_MiB.read",
+             "codec_ms_per_MiB.read", "h2d_copies_per_MiB.read",
+             "decode_issue_ms_per_MiB.read", "codec_wait_ms_per_MiB.read",
+             "transfer_ms_per_MiB.read", "media_ms_per_MiB.read",
+             "service_self_ms_per_MiB.read"},
+}
+BLOCK_CHECKS = {
+    "write": ["failed_requests", "compiles_in_window", "unpersisted_acked_blocks",
+              "readback_mismatched_blocks", "crc_mismatched_blocks",
+              "degraded_mismatched_blocks", "degraded_blocks_decoded"],
+    "read": ["failed_requests", "compiles_in_window", "read_mismatched_blocks",
+             "window_degraded_reads"],
+}
+BLOCK_LIMITS = {"degraded_blocks_decoded": (1, ">="),
+                "window_degraded_reads": (1, ">=")}
+BLOCK_CELLS = ("raid5.write.seq128k", "raid5.read.degraded4k",
+               "raid6.write.seq128k", "raid6.read.degraded4k")
+
+
+@pytest.mark.parametrize("cell", BLOCK_CELLS)
+@pytest.mark.parametrize("trace", (False, True))
+def test_block_cells_report_the_names_they_reported(cell, trace):
+    op = cell.split(".")[1]
+    r = run_tiny(cell, trace=trace)
+    assert r["correct"], r["checks"]
+    # the CPU reads no device metric: those need a chip trace
+    assert set(r["metrics"]) == (BLOCK_TRACED if trace else BLOCK_E2E)[op]
+    assert list(r["checks"]) == BLOCK_CHECKS[op]
+    for name, c in r["checks"].items():
+        assert (c["limit"], c["rule"]) == BLOCK_LIMITS.get(name, (0, "<="))

@@ -1,17 +1,17 @@
-"""The program's own host spans (``programspans.py``, ``repro.obs``) in a
-traced run of a tiny cell on the CPU: exact counts against the program's
-entry points, the new readers' values, self times within the window, spans
-per request, and the readers' arithmetic on hand-built windows."""
+"""The program's own host spans (``repro.obs.hostspans``, recorded by the
+harness's traced window onto ``Window.program``) in a traced run of a tiny
+cell on the CPU: exact counts against the program's entry points, the
+readers' values, self times within the window, spans per request, and the
+readers' arithmetic on hand-built windows."""
 import collections
+import pathlib
 import sys
-import types
 
 import pytest
 
 from tinycell import run_tiny
 
-import layers
-import programspans
+import harness
 import specs
 import tracereduce
 from repro.core.array import ZapRAIDArray
@@ -27,6 +27,9 @@ NEW_NAMES = (
     "service_self_ms_per_MiB.write", "decode_issue_ms_per_MiB.read",
     "codec_wait_ms_per_MiB.read", "transfer_ms_per_MiB.read", "media_ms_per_MiB.read",
     "service_self_ms_per_MiB.read", "programs_per_decode.read",
+    "reconstruct_ms_per_MiB.rebuild", "transfer_ms_per_MiB.rebuild",
+    "codec_wait_ms_per_MiB.rebuild", "checksum_ms_per_MiB.rebuild",
+    "media_ms_per_MiB.rebuild",
 )
 NEW = {m["name"]: m for m in BENCH["per_layer"] if m["name"] in NEW_NAMES}
 # every StripeCodec entry point that issues programs opens one codec:issue
@@ -45,7 +48,8 @@ def test_every_program_span_reader_is_in_the_benchmark():
     for name, m in NEW.items():
         assert m["source"] in ("program_span", "device_trace")
         mod = specs.load_metric(name)
-        assert mod.__doc__ and mod.programspans is programspans
+        # the readers take the spans from the window's accessors
+        assert mod.__doc__ and "w.program_" in pathlib.Path(mod.__file__).read_text()
 
 
 @pytest.fixture
@@ -54,7 +58,7 @@ def traced(monkeypatch):
     while the recorder is installed and before the window's snapshot."""
     captured = []
     calls = collections.Counter()
-    snapshot = layers.LayerSpans.snapshot
+    snapshot = hostspans.HostSpans.snapshot
 
     def capture(self):
         snap = snapshot(self)
@@ -68,7 +72,7 @@ def traced(monkeypatch):
             return fn(*args, **kw)
         return wrapped
 
-    monkeypatch.setattr(layers.LayerSpans, "snapshot", capture)
+    monkeypatch.setattr(hostspans.HostSpans, "snapshot", capture)
     crc = checksum.crc32c_many
     for mod in list(sys.modules.values()):
         if (getattr(mod, "__name__", "").startswith("repro")
@@ -84,7 +88,7 @@ def traced(monkeypatch):
         r = run_tiny(cell, trace=True)
         assert r["correct"], r["checks"]
         assert hostspans.current() is None   # the window's recorder is gone
-        return r, captured[-1][programspans.KEY]["spans"], calls
+        return r, captured[-1]["spans"], calls
     return run
 
 
@@ -112,18 +116,37 @@ def test_traced_run_counts_the_program_exactly(traced, cell):
     assert per_request <= MOST_SPANS_PER_REQUEST, (per_request, count)
 
 
-def _window(spans=None, events=None):
-    """A stand-in for ``harness.Window``: what the readers call."""
-    w = types.SimpleNamespace(
-        spans=spans, trace={"t0_ns": 100, "t1_ns": 200},
-        device_events=lambda: events,
-        per_mib_ms=lambda s, op: None if s is None else s * 1e3 / 2.0)
+def test_traced_rebuild_counts_the_program_exactly(traced):
+    r, spans, calls = traced("raid6.rebuild.2f")
+    count = {name: s["count"] for name, s in spans.items()}
+    assert count.get("checksum:crc32c", 0) == calls["crc"] > 0
+    assert count.get("codec:issue", 0) == calls["issue"] > 0
+    # a pass is one run of the event loop and two rebuild actors, each
+    # reconstructing the replaced drive's zone in one batched call
+    passes = r["checks"]["rebuild_passes"]["value"]
+    assert count["service:loop"] == passes
+    assert count["service:handle"] == count["array:reconstruct"] == 2 * passes
+    mine = {n for n, m in NEW.items() if "raid6.rebuild.2f" in m["workloads"]}
+    assert mine == set(r["metrics"])
+    window_s = r["device"]["window_s"]
+    assert sum(s["self_s"] for s in spans.values()) <= window_s
+
+
+def _window(program=None, events=None):
+    """A ``harness.Window`` of 2 MiB of every op, with the program spans and
+    the device events given."""
+    w = harness.Window(cell=None, setup_s=0.0, window_s=1.0, loop=None,
+                       block_bytes=4096, stats0={}, stats1={},
+                       spans={"self_s": {}, "dispatches": {}}, program=program,
+                       trace={"t0_ns": 100, "t1_ns": 200})
+    w.mib = lambda op: 2.0
+    w.device_events = lambda: events
     return w
 
 
 def test_readers_find_nothing_without_the_program_s_spans():
-    # a program that predates the recorder: the wrappers' snapshot only
-    for w in (_window(), _window({"self_s": {}, "dispatches": {}}, events=[])):
+    # no recorder in the window: the wrappers' snapshot only
+    for w in (_window(), _window(events=[])):
         for name in NEW:
             assert specs.load_metric(name).read(w) is None, name
 
@@ -136,7 +159,7 @@ def test_readers_sum_self_times_per_mib():
                       "service:loop": {"count": 1, "self_s": 1.0},
                       "service:handle": {"count": 9, "self_s": 0.5}},
             "dispatches": {}}
-    w = _window({programspans.KEY: snap})
+    w = _window(snap)
     read = {n: specs.load_metric(n).read(w) for n in NEW}
     assert read["transfer_ms_per_MiB.read"] == pytest.approx(500.0)
     assert read["media_ms_per_MiB.read"] == pytest.approx(250.0)
@@ -144,6 +167,9 @@ def test_readers_sum_self_times_per_mib():
     assert read["service_self_ms_per_MiB.read"] == pytest.approx(750.0)
     assert read["codec_wait_ms_per_MiB.read"] == 0.0   # never opened
     assert read["programs_per_decode.read"] is None    # no device trace
+    assert read["transfer_ms_per_MiB.rebuild"] == pytest.approx(500.0)
+    assert read["media_ms_per_MiB.rebuild"] == pytest.approx(312.5)
+    assert read["reconstruct_ms_per_MiB.rebuild"] == 0.0
 
 
 def test_programs_per_decode_counts_module_events_in_the_window():
@@ -153,5 +179,5 @@ def test_programs_per_decode_counts_module_events_in_the_window():
     events = [(mod, "jit_xor_parity", 110, 5), (mod, "jit_squeeze", 120, 5),
               (ops, "parity_xor.1", 111, 2), (mod, "jit_concatenate", 150, 5),
               (mod, "jit_xor_parity", 90, 5), (mod, "jit_stack", 250, 5)]
-    w = _window({programspans.KEY: snap}, events)
+    w = _window(snap, events)
     assert specs.load_metric("programs_per_decode.read").read(w) == 0.75

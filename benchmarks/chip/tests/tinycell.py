@@ -15,19 +15,19 @@ import specs  # noqa: E402
 # small zones and groups, a 2 MiB volume: every layer runs, GC included
 TINY_CONFIG = {"zone_cap_blocks": 512, "group_size": 16}
 TINY_TRAFFIC = {"volume_mib": 2, "prefill_mib": 2, "check_mib": 1}
-# as at full size, far fewer blocks in flight than the volume, and a
-# request a fraction of a stripe group
-TINY_QD = 4
-TINY_REQUEST_BLOCKS = 8
+# the most a runner's own traffic key may be at the tiny size, for the
+# runners whose traffic has it: as at full size, far fewer blocks in flight
+# than the volume, and a request a fraction of a stripe group
+TINY_MOST = {"qd": 4, "request_blocks": 8}
 
 
 def tiny_cell(name: str, trace: bool = False):
     cell = specs.load_cell(name, trace=trace)
     cell.config.update(TINY_CONFIG)
     cell.traffic.update(TINY_TRAFFIC)
-    cell.traffic["qd"] = min(cell.traffic["qd"], TINY_QD)
-    cell.traffic["request_blocks"] = min(cell.traffic["request_blocks"],
-                                         TINY_REQUEST_BLOCKS)
+    for key, most in TINY_MOST.items():
+        if key in cell.traffic:
+            cell.traffic[key] = min(cell.traffic[key], most)
     return cell
 
 
