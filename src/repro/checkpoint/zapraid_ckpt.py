@@ -35,6 +35,7 @@ from repro.core.raid import make_scheme
 from repro.core.recovery import recover_array
 from repro.core.segment import solve_stripes_per_segment
 from repro.core.zns import ZnsConfig
+from repro.obs.hostspans import host_span, spanned
 
 MANIFEST_LBAS = 64  # reserved logical region for the manifest
 
@@ -84,6 +85,28 @@ def state_blocks(state, block_bytes: int) -> int:
                  // block_bytes))
         for leaf in jax.tree.leaves(state)
     )
+
+
+@dataclasses.dataclass(frozen=True)
+class Shard:
+    """Where a saved array lies in its leaf of the global state: the leaf's
+    ``global_shape`` and, per axis, the ``start`` of the slice held (the
+    slice runs to ``start + shape``).  A save records both in the manifest
+    beside the saved array's own shape, as ByteCheckpoint and Orbax keep
+    a rank's shard."""
+
+    global_shape: tuple[int, ...]
+    start: tuple[int, ...]
+
+    def check(self, name: str, shape: tuple[int, ...]) -> None:
+        if not (len(self.global_shape) == len(self.start) == len(shape)) or any(
+            s < 0 or s + n > g
+            for g, s, n in zip(self.global_shape, self.start, shape)
+        ):
+            raise ValueError(
+                f"leaf {name}: a {list(shape)} slice at {list(self.start)} "
+                f"does not lie in a {list(self.global_shape)} leaf"
+            )
 
 
 def _flatten_state(state) -> tuple[list[tuple[str, np.ndarray]], Any]:
@@ -221,34 +244,47 @@ class CheckpointEngine:
             if d.failed:
                 self.array.rebuild_drive(i)
 
-    def _stage_save(self, step: int, state) -> tuple[dict, list[tuple[int, np.ndarray]]]:
+    def _stage_save(self, step: int, state, shards=None
+                    ) -> tuple[dict, list[tuple[int, np.ndarray]]]:
         """Serialize ``state`` into block extents: allocation + packing,
-        shared by the sync and async save paths."""
+        shared by the sync and async save paths.  ``shards``, a tree of
+        :class:`Shard` shaped like ``state``, adds each leaf's global shape
+        and slice to its manifest entry."""
         bb = self.cfg.block_bytes
-        leaves, _ = _flatten_state(state)
+        with host_span("ckpt", "d2h"):
+            leaves, treedef = _flatten_state(state)
+        held = [None] * len(leaves) if shards is None else treedef.flatten_up_to(shards)
+        for (name, arr), shard in zip(leaves, held):
+            if shard is not None:
+                shard.check(name, arr.shape)
         manifest = {"step": step, "leaves": {}}
         extents: list[tuple[int, np.ndarray]] = []
-        for name, arr in leaves:
-            raw = arr.tobytes()
-            n_blocks = max(1, -(-len(raw) // bb))
-            lba = self._alloc(n_blocks)
-            buf = np.zeros((n_blocks, bb), np.uint8)
-            flat = np.frombuffer(raw, np.uint8)
-            buf.reshape(-1)[: flat.size] = flat
-            extents.append((lba, buf))
-            manifest["leaves"][name] = {
-                "lba": lba,
-                "n_blocks": n_blocks,
-                "nbytes": len(raw),
-                "dtype": str(arr.dtype),
-                "shape": list(arr.shape),
-            }
+        with host_span("ckpt", "pack"):
+            for (name, arr), shard in zip(leaves, held):
+                raw = arr.tobytes()
+                n_blocks = max(1, -(-len(raw) // bb))
+                lba = self._alloc(n_blocks)
+                buf = np.zeros((n_blocks, bb), np.uint8)
+                flat = np.frombuffer(raw, np.uint8)
+                buf.reshape(-1)[: flat.size] = flat
+                extents.append((lba, buf))
+                entry = manifest["leaves"][name] = {
+                    "lba": lba,
+                    "n_blocks": n_blocks,
+                    "nbytes": len(raw),
+                    "dtype": str(arr.dtype),
+                    "shape": list(arr.shape),
+                }
+                if shard is not None:
+                    entry["global_shape"] = list(shard.global_shape)
+                    entry["start"] = list(shard.start)
         return manifest, extents
 
-    def save(self, step: int, state) -> dict:
-        """Append a checkpoint for ``step``; returns its manifest."""
+    def save(self, step: int, state, shards=None) -> dict:
+        """Append a checkpoint for ``step``; returns its manifest.
+        ``shards``: see :meth:`_stage_save`."""
         self._ensure_lanes()
-        manifest, extents = self._stage_save(step, state)
+        manifest, extents = self._stage_save(step, state, shards)
         for lba, buf in extents:
             self.array.write(lba, buf)
         self.array.flush()
@@ -258,6 +294,7 @@ class CheckpointEngine:
         self._retire_old()
         return manifest
 
+    @spanned("ckpt", "manifest")
     def _manifest_blocks(self) -> np.ndarray:
         bb = self.cfg.block_bytes
         blob = json.dumps(self.catalog).encode()
@@ -280,7 +317,8 @@ class CheckpointEngine:
     # ------------------------------------------------- async (service tier)
 
     def save_async(self, step: int, state, *, service, tenant: str = "ckpt",
-                   at: Optional[float] = None, cb=None) -> SaveTicket:
+                   at: Optional[float] = None, cb=None,
+                   shards=None) -> SaveTicket:
         """Stream a checkpoint through a block service as tenant traffic.
 
         One write request per leaf extent enters the tenant's submission
@@ -289,11 +327,12 @@ class CheckpointEngine:
         acked, preserving the crash-ordering invariant of the sync path
         (a manifest never points at unwritten extents).  The returned
         ticket resolves at the manifest's device-completion time.
+        ``shards``: see :meth:`_stage_save`.
 
         Unlike :meth:`save`, failed lanes are not rebuilt inline -- in the
         timed world a rebuild is an engine actor
         (``HandlerPipeline.schedule_rebuild``), not a synchronous call."""
-        manifest, extents = self._stage_save(step, state)
+        manifest, extents = self._stage_save(step, state, shards)
         self.catalog[step] = manifest
         self.saves += 1
         self._retire_old()

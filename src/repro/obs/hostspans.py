@@ -49,6 +49,7 @@ SPANS = (
     "checksum:crc32c",
     "codec:h2d", "codec:issue", "codec:wait", "codec:d2h", "codec:pack",
     "codec:meta",
+    "ckpt:d2h", "ckpt:pack", "ckpt:manifest",
 )
 
 

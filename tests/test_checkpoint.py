@@ -175,3 +175,158 @@ def test_restart_determinism():
         p2, o2, m = train_step(p2, o2, batch)
         relosses.append(float(m["loss"]))
     np.testing.assert_allclose(relosses, losses[3:], rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------- async saves at the paper's geometry
+
+def _timed_engine(n_zones=8, state_blocks_=None, keep_last=2):
+    """An engine over a timed RAID-5 (3+1) pipeline at the paper's geometry
+    (G=256 stripes, one-block chunks) on small zones, behind a block
+    service; its ring holds ``keep_last + 1`` saves of ``state_blocks_``."""
+    from repro.checkpoint.zapraid_ckpt import MANIFEST_LBAS
+    from repro.service import BlockDeviceService, QosClass
+
+    cfg = CheckpointConfig(n_lanes=4, scheme="raid5", group_size=256,
+                           chunk_blocks=1, block_bytes=4096,
+                           zone_cap_blocks=512, n_zones=n_zones,
+                           keep_last=keep_last)
+    logical = MANIFEST_LBAS + (keep_last + 1) * state_blocks_
+    ckpt, pipe = CheckpointEngine.build_timed(cfg, logical, seed=5)
+    svc = BlockDeviceService(pipe, max_inflight=64, policy="fifo")
+    svc.register("ckpt", QosClass("ckpt", queue_cap=1 << 30))
+    return ckpt, svc
+
+
+def _bits_state(seed):
+    """A train-state-like tree of drawn bits: NaN patterns included, so it
+    is compared as bytes."""
+    rng = np.random.default_rng(seed)
+
+    def bits(shape, dtype):
+        n = int(np.prod(shape)) * np.dtype(dtype).itemsize
+        return np.frombuffer(rng.bytes(n), dtype).reshape(shape)
+
+    return {
+        "params": {"w": bits((40, 2048), jnp.bfloat16), "b": bits((96,), jnp.bfloat16)},
+        "opt": {"step": np.int32(seed),
+                "m": {"w": bits((40, 2048), np.float32), "b": bits((96,), np.float32)},
+                "v": {"w": bits((40, 2048), np.float32), "b": bits((96,), np.float32)}},
+    }
+
+
+def _as_bytes(state) -> dict:
+    flat, _ = jax.tree_util.tree_flatten_with_path(state)
+    return {jax.tree_util.keystr(p): np.asarray(x).tobytes() for p, x in flat}
+
+
+def _save(ckpt, svc, step, state, **kw):
+    ticket = ckpt.save_async(step, state, service=svc, **kw)
+    svc.drain()
+    assert ticket.done
+    return ticket
+
+
+def _restored_bytes(ckpt, svc, step, like):
+    ticket = ckpt.restore_async(step, like, service=svc)
+    svc.drain()
+    assert ticket.done
+    return _as_bytes(ticket.state)
+
+
+def test_async_save_and_restore_at_the_papers_geometry():
+    from repro.checkpoint.zapraid_ckpt import state_blocks
+
+    per_save = state_blocks(_bits_state(0), 4096)
+    ckpt, svc = _timed_engine(state_blocks_=per_save)
+    saved = {}
+    for step in (1, 2):
+        saved[step] = _as_bytes(_bits_state(step))
+        _save(ckpt, svc, step, _bits_state(step))
+    like = _bits_state(0)
+    assert _restored_bytes(ckpt, svc, 2, like) == saved[2]
+    ckpt.fail_lane(1)
+    d0 = ckpt.array.stats.degraded_reads
+    for step in (1, 2):
+        assert _restored_bytes(ckpt, svc, step, like) == saved[step]
+    assert ckpt.array.stats.degraded_reads > d0
+
+
+def test_async_saves_past_the_ring_and_a_segment_restore_every_kept_one():
+    from repro.checkpoint.zapraid_ckpt import state_blocks
+    from repro.core.segment import solve_stripes_per_segment
+
+    per_save = state_blocks(_bits_state(0), 4096)
+    stripes, _ = solve_stripes_per_segment(512, 1, 4096)
+    ckpt, svc = _timed_engine(n_zones=4, state_blocks_=per_save)
+    saved = {}
+    step = 0
+    # until GC has run, then three saves more: a segment holds several
+    # saves, the ring three
+    while ckpt.array.stats.gc_runs == 0 or step < gc_step + 3:
+        step += 1
+        saved[step] = _as_bytes(_bits_state(step))
+        _save(ckpt, svc, step, _bits_state(step))
+        if ckpt.array.stats.gc_runs == 0:
+            gc_step = step + 1
+        assert step < 100
+    assert step * per_save > 3 * stripes > 3 * per_save
+    assert sorted(ckpt.catalog) == [step - 1, step]
+    for step in ckpt.catalog:
+        assert _restored_bytes(ckpt, svc, step, _bits_state(0)) == saved[step]
+
+
+def test_shard_metadata_round_trips():
+    from repro.checkpoint.zapraid_ckpt import Shard, state_blocks
+
+    state = _bits_state(3)
+    # rank 1 of two along each leaf's last axis; the step whole
+    shards = jax.tree.map(
+        lambda x: Shard(tuple(np.shape(x)[:-1]) + (2 * np.shape(x)[-1],)
+                        if np.ndim(x) else (), (0,) * (np.ndim(x) - 1)
+                        + ((np.shape(x)[-1],) if np.ndim(x) else ())),
+        state)
+    ckpt, svc = _timed_engine(state_blocks_=state_blocks(state, 4096))
+    ticket = _save(ckpt, svc, 7, state, shards=shards)
+    w = ticket.manifest["leaves"]["['params']['w']"]
+    assert (w["shape"], w["global_shape"], w["start"]) == ([40, 2048], [40, 4096], [0, 2048])
+    step = ticket.manifest["leaves"]["['opt']['step']"]
+    assert (step["shape"], step["global_shape"], step["start"]) == ([], [], [])
+    # the manifest persisted with the shard fields: a remount reads them back
+    remounted = ckpt.crash_and_remount()
+    assert remounted.catalog[7] == ticket.manifest
+    # without shards the manifest is as before: no shard fields
+    plain = _save(ckpt, svc, 8, state).manifest
+    assert all(set(e) == {"lba", "n_blocks", "nbytes", "dtype", "shape"}
+               for e in plain["leaves"].values())
+    # a slice that does not lie in its leaf is refused before anything is
+    # allocated
+    bad = dict(shards, params=dict(shards["params"], w=Shard((40, 2048), (0, 1))))
+    with pytest.raises(ValueError, match="does not lie"):
+        ckpt.save_async(9, state, service=svc, shards=bad)
+
+
+def test_checkpoint_spans_open_and_leave_results_unchanged():
+    from repro.checkpoint.zapraid_ckpt import state_blocks
+    from repro.obs.hostspans import HostSpans
+
+    runs = []
+    for record in (False, True):
+        state = _bits_state(4)
+        ckpt, svc = _timed_engine(state_blocks_=state_blocks(state, 4096))
+        rec = HostSpans(annotate=False).install() if record else None
+        try:
+            ticket = _save(ckpt, svc, 1, state)
+        finally:
+            if rec is not None:
+                rec.uninstall()
+        if rec is not None:
+            spans = rec.snapshot()["spans"]
+            for name in ("ckpt:d2h", "ckpt:pack", "ckpt:manifest"):
+                assert spans[name]["count"] >= 1, name
+        arr = ckpt.array
+        runs.append((ticket.manifest, ticket.t_done, arr.l2p.flat.copy(),
+                     [d.data.copy() for d in arr.drives]))
+    off, on = runs
+    assert off[:2] == on[:2]
+    assert np.array_equal(off[2], on[2])
+    assert all(np.array_equal(a, b) for a, b in zip(off[3], on[3]))

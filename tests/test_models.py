@@ -67,6 +67,16 @@ def test_arch_param_count_matches_config_formula(arch):
     )
 
 
+def test_qwen2_5_3b_has_its_published_parameter_count():
+    """Tied embeddings, as ``Qwen/Qwen2.5-3B``'s ``tie_word_embeddings``:
+    the model card's 3.09 B parameters, QKV biases included.  Shapes only:
+    nothing of the full width is allocated."""
+    model = build_model(get_config("qwen2.5-3b"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(shapes)) == 3_085_938_688
+    assert "lm_head" not in shapes
+
+
 @pytest.mark.parametrize("arch", ["smollm-135m", "qwen2.5-3b", "mamba2-1.3b",
                                   "zamba2-2.7b", "whisper-small",
                                   "llama4-scout-17b-a16e"])
