@@ -9,6 +9,7 @@ import pathlib
 import numpy as np
 import pytest
 
+from repro.integrity.checksum import R_MIN, crc32c_many
 from repro.obs import HostSpans, host_span
 from repro.obs import hostspans
 from repro.obs.hostspans import NO_SPAN, SPANS, spanned
@@ -182,6 +183,29 @@ def test_uninstall_only_removes_its_own_recorder():
     assert hostspans.current() is a
     a.uninstall()
     assert hostspans.current() is None
+
+
+@pytest.mark.parametrize("device", (False, True))
+def test_crc32c_many_opens_one_span_keyed_by_its_path(device):
+    """Either path is one checksum:crc32c span and no other (its self time
+    is its total), keyed by the path and ``(N, L)``; the CRCs are the same
+    bits with the recorder on or off."""
+    blocks = np.random.default_rng(5).integers(0, 256, (R_MIN + 3, 512),
+                                               dtype=np.uint8)
+    off = crc32c_many(blocks, device=device)
+    rec = HostSpans(annotate=False).install()
+    try:
+        on = crc32c_many(blocks, device=device)
+    finally:
+        rec.uninstall()
+    snap = rec.snapshot()
+    assert list(snap["spans"]) == ["checksum:crc32c"]
+    s = snap["spans"]["checksum:crc32c"]
+    assert s["count"] == 1 and s["self_s"] == s["total_s"] > 0
+    op = "crc32c_device" if device else "crc32c_host"
+    assert snap["dispatches"] == {(op, ((R_MIN + 3, 512),)): 1}
+    np.testing.assert_array_equal(on, off)
+    np.testing.assert_array_equal(on, crc32c_many(blocks, device=not device))
 
 
 def _call_sites():

@@ -11,6 +11,7 @@ double fault surfacing :class:`IntegrityError` instead of garbage.
 """
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -27,6 +28,9 @@ from repro.core.segment import (
 )
 from repro.core.zns import OOB_DTYPE, ZnsConfig
 from repro.integrity import CRC_BYTES, crc32c, crc32c_many, crc32c_pack, verify_many
+from repro.integrity import checksum
+from repro.integrity.checksum import R_MIN, R_TILE, crc32c_device
+from repro.obs import HostSpans
 from repro.sim.faults import MEDIA_KINDS, FaultEvent, FaultPlan
 
 BB = 256
@@ -56,6 +60,104 @@ def test_crc32c_many_matches_scalar():
     assert ok.all()
     blocks[5, 0] ^= 1
     assert not verify_many(blocks, many)[5]
+
+
+# --------------------------------------------------- device CRC32C (GF(2) product)
+
+BUCKETS = [R_MIN << i for i in range((R_TILE // R_MIN).bit_length())]
+DEVICE_ROWS = sorted({1, R_MIN - 1, *BUCKETS, R_TILE + 1, 2 * R_TILE + 17})
+
+
+def _rows(fill, n, length):
+    if fill == "random":
+        return np.random.default_rng(n * 7 + length).integers(
+            0, 256, (n, length), dtype=np.uint8)
+    return np.full((n, length), 0 if fill == "zeros" else 0xFF, np.uint8)
+
+
+@pytest.mark.parametrize("fill", ("random", "zeros", "ones"))
+@pytest.mark.parametrize("length", (4096, BB))
+@pytest.mark.parametrize("n", DEVICE_ROWS)
+def test_crc32c_device_matches_table_walk(fill, length, n):
+    """The device path, forced on this backend, gives the table walk's bits
+    on uint8 rows and on the int32-packed arena view of the same bytes."""
+    blocks = _rows(fill, n, length)
+    host = crc32c_many(blocks, device=False)
+    assert host.dtype == np.uint32 and host.shape == (n,)
+    for i in {0, n - 1}:
+        assert int(host[i]) == crc32c(blocks[i].tobytes())
+    arena = blocks.view(np.int32)
+    for got in (crc32c_many(blocks, device=True), crc32c_many(arena, device=True),
+                crc32c_device(blocks), crc32c_device(arena)):
+        assert got.dtype == np.uint32
+        np.testing.assert_array_equal(got, host)
+
+
+@pytest.mark.parametrize("length", (4096, BB))
+def test_crc32c_device_compiles_nothing_after_its_first_call(length):
+    compiles = []
+
+    def listener(event, duration, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    crc32c_device(_rows("random", 1, length))   # compiles every bucket
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        for n in DEVICE_ROWS:
+            crc32c_device(_rows("random", n, length))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert compiles == []
+
+
+@pytest.mark.parametrize("tpu,n,length,path", [
+    (False, R_TILE, 4096, "crc32c_host"),
+    (True, R_TILE, 4096, "crc32c_device"),
+    (True, R_MIN, BB, "crc32c_device"),
+    (True, R_MIN - 1, 4096, "crc32c_host"),
+    (True, R_MIN, 6, "crc32c_host"),          # not whole int32 words
+    (True, R_MIN, 0, "crc32c_host"),
+])
+def test_default_path_follows_platform_and_shape(monkeypatch, tpu, n, length, path):
+    monkeypatch.setattr(checksum, "on_tpu", lambda: tpu)
+    blocks = _rows("random", n, length)
+    rec = HostSpans(annotate=False).install()
+    try:
+        got = crc32c_many(blocks)
+    finally:
+        rec.uninstall()
+    assert rec.dispatches == {(path, ((n, length),)): 1}
+    np.testing.assert_array_equal(got, crc32c_many(blocks, device=False))
+
+
+@pytest.mark.parametrize("scheme,n", [("raid5", 4), ("raid6", 6)])
+def test_device_path_commits_store_the_host_path_s_media(monkeypatch, scheme, n):
+    """A small array whose group commits and rebuild take the device path
+    (as on a TPU) stores the same CRC plane and media as the host path."""
+    def run(tpu):
+        monkeypatch.setattr(checksum, "on_tpu", lambda: tpu)
+        cfg = ZapRaidConfig(scheme=scheme, n_drives=n, group_size=16,
+                            chunk_blocks=1, logical_blocks=256,
+                            gc_free_segments_low=1)
+        arr = ZapRAIDArray(cfg, ZnsConfig(n_zones=8, zone_cap_blocks=96,
+                                          block_bytes=BB))
+        rec = HostSpans(annotate=False).install()
+        try:
+            _fill(arr, seed=11)
+            arr.fail_drive(1)
+            arr.rebuild_drive(1)
+        finally:
+            rec.uninstall()
+        return arr, {op for op, _ in rec.dispatches if op.startswith("crc32c")}
+
+    dev, dev_ops = run(True)
+    host, host_ops = run(False)
+    assert "crc32c_device" in dev_ops and host_ops == {"crc32c_host"}
+    for d1, d0 in zip(dev.drives, host.drives):
+        np.testing.assert_array_equal(d1.crc, d0.crc)
+        np.testing.assert_array_equal(d1.data, d0.data)
+        np.testing.assert_array_equal(d1.wp, d0.wp)
 
 
 # ------------------------------------------------------------ helpers

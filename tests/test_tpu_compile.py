@@ -10,7 +10,10 @@ encode and RS decode for every survivor set; the single-stripe XOR that
 of a RAID-6 degraded read.  Each case compiles through the ``ops`` entry
 point the codec calls and asserts a Pallas TPU kernel (``tpu_custom_call``)
 in the compiled program, under the kernel's stable name (``name=`` on its
-``pallas_call``), which a profile shows as the kernel's op.
+``pallas_call``), which a profile shows as the kernel's op.  Beside them,
+the checksum layer's device CRC32C (plain XLA, one int8 bit-matrix
+product) compiles at its smallest and largest row bucket of 4 KiB blocks
+without writing the unpacked bits to a temporary buffer.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -24,6 +27,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import gf
+from repro.integrity import checksum
 from repro.kernels import ops
 
 S = 256
@@ -128,3 +132,15 @@ def test_raid6_single_stripe_rs_decode_compiles(one_chip, n):
     )
     assert "tpu_custom_call" in text
     assert _has_kernel(text, "gf256_matmul")
+
+
+@pytest.mark.parametrize("rows", (checksum.R_MIN, checksum.R_TILE))
+def test_crc32c_device_product_compiles(one_chip, rows):
+    """The (rows, 1024) int32 words of 4 KiB blocks against the (32, 1024,
+    32) int8 bit matrix: the bits (rows x 32 KiB) are unpacked into the
+    product's operand, not into a temporary buffer of the program."""
+    words = jax.ShapeDtypeStruct((rows, 1024), jnp.int32, sharding=one_chip)
+    bmat = jax.ShapeDtypeStruct((32, 1024, 32), jnp.int8, sharding=one_chip)
+    _, const = checksum._pos_tables(4096)
+    compiled = checksum._crc_rows.lower(words, bmat, const=const).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * 32 * 1024
