@@ -1,7 +1,7 @@
 PYTHONPATH := src
 PY := PYTHONPATH=$(PYTHONPATH) python
 
-.PHONY: test test-fast bench bench-quick bench-check serve-demo cache-demo obs-demo degraded-demo scrub-demo
+.PHONY: test test-fast serve-demo cache-demo obs-demo degraded-demo scrub-demo
 
 # Tier-1 verify: the whole suite, stop on first failure.
 test:
@@ -10,20 +10,6 @@ test:
 # Skip the slow system/checkpoint suites during iteration.
 test-fast:
 	$(PY) -m pytest -x -q --ignore=tests/test_system.py --ignore=tests/test_checkpoint.py
-
-# Full benchmark sweep; writes BENCH_FULL.json (gitignored) next to the CSV.
-bench:
-	$(PY) -m benchmarks.run
-
-# Cheap subset with small shapes for CI time budgets; rewrites the committed
-# BENCH_PR10.json baseline (the quick set carries the perf acceptance figures).
-bench-quick:
-	$(PY) -m benchmarks.run --quick
-
-# CI regression gate: rerun the quick set, fail on >25% wall-clock regression
-# against the committed baseline (writes no JSON).
-bench-check:
-	$(PY) -m benchmarks.run --check BENCH_PR10.json
 
 # Checkpoint-traffic-under-serving demo: many training jobs stream saves
 # through the async block service while latency-class reads run alongside;

@@ -15,7 +15,6 @@ cfg = ZapRaidConfig(
     scheme="raid5", n_drives=4,
     group_size=16,        # G: stripes per Zone-Append group (paper 3.2)
     chunk_blocks=1, logical_blocks=512, gc_free_segments_low=1,
-    use_pallas=True,      # Pallas parity kernels (compiled on TPU, interpreted elsewhere)
 )
 zns = ZnsConfig(n_zones=16, zone_cap_blocks=128, block_bytes=4096)
 arr = ZapRAIDArray(cfg, zns)

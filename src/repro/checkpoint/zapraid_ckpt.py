@@ -50,11 +50,6 @@ class CheckpointConfig:
     zone_cap_blocks: int = 4096
     n_zones: int = 64
     keep_last: int = 2
-    # datapath: None follows the backend -- compiled Pallas kernels on a
-    # TPU, the jitted jnp oracle elsewhere (interpret-mode Pallas runs the
-    # kernel body in Python and is for kernel validation only)
-    use_pallas: Optional[bool] = None
-    interpret: Optional[bool] = None
 
     def zap_cfg(self, logical_blocks: int) -> ZapRaidConfig:
         return ZapRaidConfig(
@@ -64,8 +59,6 @@ class CheckpointConfig:
             chunk_blocks=self.chunk_blocks,
             logical_blocks=logical_blocks,
             gc_free_segments_low=2,
-            use_pallas=self.use_pallas,
-            interpret=self.interpret,
         )
 
     def zns_cfg(self) -> ZnsConfig:

@@ -110,17 +110,7 @@ class ZapRaidConfig:
     # *store* is always maintained at commit time, only the read-side verify
     # pass is optional (bit-identity with pre-integrity baselines).
     verify_reads: bool = False
-    # datapath: codec mode; None follows the backend (compiled Pallas
-    # kernels on a TPU, the jnp reference elsewhere -- repro.kernels.backend)
-    use_pallas: Optional[bool] = None
-    interpret: Optional[bool] = None
     batched: bool = True           # group-level fused encode + vectorized I/O
-    # double-buffered group commits: the fused encode for group g+1 is
-    # dispatched (JAX async, donated buffers) before group g's chunks are
-    # committed to the drives, with explicit syncs at reads, flush, seal, GC
-    # and crash-arming.  Only active on the untimed functional path (the
-    # timed pipeline's group barrier is already a sync point).
-    overlap: bool = True
     append_seed: int = 1234
     # Zone-Append completion-order source: "timed" derives the disorder from
     # the discrete-event device model (fastest command wins the write
@@ -189,9 +179,8 @@ class _StripeArena:
     a permanently-zero row used to pad partial groups up to the codec's
     power-of-two shape buckets with a single fancy-index gather.
 
-    Sized for two full stripe groups plus slack: one group staged in the
-    segment's ``group_buffer`` while the previous (double-buffered) group is
-    still pending commit, plus the in-flight stripe.
+    Sized for two full stripe groups plus slack; a stripe that finds the
+    arena drained falls back to private arrays (same bytes, not packed).
     """
 
     def __init__(self, k: int, chunk_blocks: int, block_bytes: int, group_size: int):
@@ -348,9 +337,7 @@ class ZapRAIDArray:
         self.cfg = cfg
         self.zns_cfg = zns_cfg
         self.scheme = make_scheme(cfg.scheme, cfg.n_drives)
-        self.codec = StripeCodec(
-            self.scheme, use_pallas=cfg.use_pallas, interpret=cfg.interpret
-        )
+        self.codec = StripeCodec(self.scheme)
         self.stats = Stats()
         self.codec.copy_stats = self.stats
         self.budget = CrashBudget(None)
@@ -403,10 +390,8 @@ class ZapRAIDArray:
             entries_per_group=zns_cfg.block_bytes // 4,
         )
         self._in_flight: dict[int, _InFlightStripe] = {}  # per segment class
-        # device-resident staging: one packed arena per segment class, and at
-        # most one built-but-uncommitted (double-buffered) stripe group
+        # device-resident staging: one packed arena per segment class
         self._arenas: dict[int, _StripeArena] = {}
-        self._pending_group: Optional[dict] = None
         # Latest committed write-timestamp per LBA / mapping group.  Commits
         # can complete out of order across segments (a buffered Zone-Append
         # group lands after a later Zone-Write stripe), so L2P updates are
@@ -470,10 +455,7 @@ class ZapRAIDArray:
     def _codec_for_width(self, width: int) -> StripeCodec:
         codec = self._codecs.get(width)
         if codec is None:
-            codec = StripeCodec(
-                self._scheme_for_width(width),
-                use_pallas=self.cfg.use_pallas, interpret=self.cfg.interpret,
-            )
+            codec = StripeCodec(self._scheme_for_width(width))
             codec.copy_stats = self.stats
             self._codecs[width] = codec
         return codec
@@ -574,16 +556,13 @@ class ZapRAIDArray:
 
     def has_staged(self) -> bool:
         """True while foreground work sits in volatile staging: buffered
-        blocks of partially filled stripes, a built-but-uncommitted stripe
-        group (double buffering), or mapping blocks awaiting their metadata
-        write.  The timed pipeline's timeout-flush tick and the service
-        tier's idle detection use this to decide whether a ``flush()`` is
-        still owed before the system may go quiet."""
-        return (
-            bool(self._buffered)
-            or self._pending_group is not None
-            or bool(self._pending_meta)
-        )
+        blocks of partially filled stripes or mapping blocks awaiting their
+        metadata write.  A full stripe group is on the media by the time the
+        write that filled it returns, so it never counts.  The timed
+        pipeline's timeout-flush tick and the service tier's idle detection
+        use this to decide whether a ``flush()`` is still owed before the
+        system may go quiet."""
+        return bool(self._buffered) or bool(self._pending_meta)
 
     # ------------------------------------------------------------- cache tier
 
@@ -829,9 +808,6 @@ class ZapRAIDArray:
                 if ost.group_buffer:
                     self._commit_group(ost)
                     progressed = True
-            if self._pending_group is not None:
-                self._sync_pending()
-                progressed = True
 
     @spanned("array", "commit")
     def flush(self) -> None:
@@ -887,13 +863,6 @@ class ZapRAIDArray:
             ost = self.open_segments[sid]
         return ost
 
-    def _pending_count(self, ost: _OpenSegment) -> int:
-        """Stripes built-but-uncommitted (double-buffered) for this segment."""
-        pend = self._pending_group
-        if pend is not None and pend["ost"] is ost:
-            return len(pend["seqs"])
-        return 0
-
     def _dispatch_stripe(self, seg_class: int) -> None:
         stripe = self._in_flight.pop(seg_class)
         ost = self._select_segment(seg_class)
@@ -902,11 +871,7 @@ class ZapRAIDArray:
             # group-commit time so on-disk timestamps reflect commit order.
             ost.group_buffer.append(stripe)
             gsz = ost.info.group_size
-            staged = (
-                ost.info.stripes_written
-                + self._pending_count(ost)
-                + len(ost.group_buffer)
-            )
+            staged = ost.info.stripes_written + len(ost.group_buffer)
             if staged % gsz == 0 or staged == ost.info.n_stripes:
                 self._commit_group(ost)
         else:
@@ -989,8 +954,8 @@ class ZapRAIDArray:
         via the arena's permanent zero slot) and handed to the codec's
         donating async entry point.  The returned group dict carries the
         un-materialized device parity; :meth:`_commit_built_group` syncs on
-        it, which is what makes double-buffered commits overlap host commit
-        work for group g with the encode of group g+1.
+        it, so the encode runs while the host assembles the group's
+        out-of-band records and completion order.
         """
         info = ost.info
         k, m, c = info.k, info.m, info.chunk_blocks
@@ -1058,9 +1023,8 @@ class ZapRAIDArray:
             par_oob["stripe"] = seqs[:, None, None]
         else:
             par_oob = np.zeros((s_count, 0, c), dtype=OOB_DTYPE)
-        # Zone-Append completion order is drawn at build time so the RNG /
-        # device-plan sequence matches the synchronous commit path even when
-        # the drive commit itself is deferred one group.
+        # Zone-Append completion order is drawn at build time, in the same
+        # RNG / device-plan sequence as the scalar commit path.
         ops_list = [
             (s_i, d) for s_i in range(s_count) for d in range(info.n_drives)
         ]
@@ -1112,46 +1076,21 @@ class ZapRAIDArray:
     def _commit_group(self, ost: _OpenSegment) -> None:
         """Zone-Append group commit with globally shuffled completion order.
 
-        On the batched datapath this builds the group, dispatches its fused
-        encode asynchronously, commits the *previous* deferred group (whose
-        encode has been running meanwhile), and -- when overlap is on and no
-        sync point forces otherwise -- leaves the new group pending for the
-        next commit/sync, i.e. double-buffering."""
-        info = ost.info
+        On the batched datapath this builds the group, which dispatches its
+        fused encode asynchronously, and commits it at once: the group is on
+        the media when this returns."""
         if not ost.group_buffer:
             return
         if not self.cfg.batched:
             self._commit_group_legacy(ost)
             return
-        pend = self._pending_group
-        pc = len(pend["seqs"]) if (pend is not None and pend["ost"] is ost) else 0
-        seq0 = info.stripes_written + pc
-        grp = self._build_group(ost, ost.group_buffer, seq0)
+        grp = self._build_group(ost, ost.group_buffer, ost.info.stripes_written)
         ost.group_buffer = []
-        end_of_segment = seq0 + len(grp["seqs"]) == info.n_stripes
-        self._sync_pending()  # overlaps with grp's in-flight encode
-        defer = (
-            self.cfg.overlap
-            and not end_of_segment
-            and self.budget.remaining is None
-            and self.append_plan_fn is None
-            and self.commit_listener is None
-        )
-        if defer:
-            self._pending_group = grp
-        else:
-            self._commit_built_group(grp)
-
-    def _sync_pending(self) -> None:
-        """Explicit sync point: commit the deferred (double-buffered) group."""
-        if self._pending_group is not None:
-            grp = self._pending_group
-            self._pending_group = None
-            self._commit_built_group(grp)
+        self._commit_built_group(grp)
 
     @spanned("array", "commit")
     def _commit_built_group(self, grp: dict) -> None:
-        """Materialize the group's device parity and commit it to the drives.
+        """Wait for the group's device parity and commit it to the drives.
 
         Normal path: one bulk Zone-Append run per drive (the per-drive issue
         subsequence of the shuffled completion order) plus fully vectorized
@@ -1489,11 +1428,10 @@ class ZapRAIDArray:
 
     def _maybe_seal(self, ost: _OpenSegment) -> None:
         info = ost.info
-        if info.stripes_written + self._pending_count(ost) < info.n_stripes:
+        if info.stripes_written < info.n_stripes:
             return
         if ost.group_buffer:
             self._commit_group(ost)
-        self._sync_pending()  # the tail group must land before the footer
         self._seal_segment(ost)
 
     def _seal_segment(self, ost: _OpenSegment) -> None:
@@ -1537,7 +1475,6 @@ class ZapRAIDArray:
 
     @spanned("array", "fetch")
     def read(self, lba: int, n_blocks: int = 1) -> np.ndarray:
-        self._sync_pending()  # read-your-writes: deferred group must land
         self.stats.reads += n_blocks
         # single-block reads keep the scalar path: the gather/group machinery
         # costs more than it saves below ~2 blocks (random-read hot path)
@@ -2296,9 +2233,6 @@ class ZapRAIDArray:
         int32-packed arenas (the donated fused re-encode); mapping blocks
         batch the same way.  The scalar path stays as the bit-identical
         per-block baseline."""
-        # deferred commits must land first: GC reads validity/L2P state that a
-        # pending group is about to update (its old copies would look live)
-        self._sync_pending()
         rec = self._gc_select_victim()
         if rec is None:
             return False
@@ -2416,7 +2350,6 @@ class ZapRAIDArray:
         freeze until rebuild re-adopts them.  When the scheme cannot operate
         at the survivor width (raid6 past two failures, raid0 data loss) the
         rotation is left alone and the next write raises."""
-        self._sync_pending()  # the deferred group still owns healthy drives
         self.drives[drive_idx].fail()
         try:
             self._scheme_for_width(len(self._active_drive_ids()))
@@ -2432,7 +2365,6 @@ class ZapRAIDArray:
         Returns [(seg_class, lba, block, meta_gid)] in staging order and
         releases the arena slots -- the caller restages after changing the
         write rotation (fail_drive / _rewiden)."""
-        self._sync_pending()
         staged: list[tuple[int, int, np.ndarray, int]] = []
 
         def collect(seg_class: int, stripe: _InFlightStripe) -> None:
@@ -2480,7 +2412,7 @@ class ZapRAIDArray:
             info = ost.info
             if info.drive_ids != ids:
                 continue
-            if info.stripes_written + self._pending_count(ost) >= info.n_stripes:
+            if info.stripes_written >= info.n_stripes:
                 continue  # data-complete: will seal, not take new stripes
             if any((sid, d) in self._rebuild_pending for d in range(info.n_drives)):
                 continue
@@ -2549,7 +2481,6 @@ class ZapRAIDArray:
         """Full-drive recovery (§3.5) onto a replacement drive, then
         re-widen: survivor-width segments written while degraded are
         re-encoded at full width and backfilled across all drives."""
-        self._sync_pending()
         self.drives[drive_idx].replace()
         scaffold: dict[int, tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
         for rec in sorted(self.segments.values(), key=lambda r: r.info.seg_id):
@@ -2800,7 +2731,6 @@ class ZapRAIDArray:
         The timed pipeline's paced actor walks segments one per tick
         instead (:meth:`HandlerPipeline.schedule_scrub`); this synchronous
         form is for tests and crash-free tooling."""
-        self._sync_pending()
         totals = {"verified": 0, "detected": 0, "repaired": 0,
                   "skipped_members": 0, "segments": 0}
         for seg_id in sorted(self.segments):
@@ -2818,15 +2748,11 @@ class ZapRAIDArray:
 
     def arm_crash(self, blocks_from_now: int) -> None:
         """Next ``blocks_from_now`` block commits succeed; later ones crash."""
-        # a deferred group predates the arming (the synchronous path would
-        # already have committed it), so land it before the budget bites
-        self._sync_pending()
         self.budget.remaining = blocks_from_now
 
     def disarm_crash(self) -> None:
         self.budget.remaining = None
 
     def logical_utilization(self) -> float:
-        self._sync_pending()
         live = sum(r.valid_count for r in self.segments.values())
         return live / max(1, self.cfg.logical_blocks)

@@ -500,7 +500,6 @@ class HandlerPipeline:
         arr = self.array
         eng = self.engine
         mark = eng.mark_io()
-        arr._sync_pending()
         arr.drives[drive_idx].replace()
         scaffold: dict = {}
         sealed = []  # (seg_id, member index of the replaced drive)
@@ -623,7 +622,6 @@ class HandlerPipeline:
     ) -> None:
         from repro.core.segment import SegmentState
         arr = self.array
-        arr._sync_pending()
         sealed = sorted(
             sid for sid, rec in arr.segments.items()
             if rec.info.state == int(SegmentState.SEALED)

@@ -3,9 +3,9 @@
 We cannot measure a real ZNS SSD in this environment, so the paper's own
 measurements (§2.2, Figure 2; Exp#1 Figure 6; Exp#3 Figure 8) are used as
 the calibration surface for an analytic throughput/latency model.  The
-benchmarks replay the paper's experiment sweeps through this model plus the
-*functional* simulator (for metadata/query/recovery costs measured for real),
-and must reproduce the paper's qualitative trends:
+discrete-event drive model (:mod:`repro.sim.device`) samples its service
+times from these means, and the model must reproduce the paper's
+qualitative trends (``tests/test_distributed.py``):
 
 * Zone Append > Zone Write for small writes on few open zones (intra-zone
   parallelism, saturating at ~4 outstanding appends per zone);
@@ -226,21 +226,3 @@ def degraded_read_latency_us(
     decode_us = 0.4 * chunk_kib * k / 3.0
     query_us = (k * group_size * cst_entry_ns) / 1e3
     return read_us + decode_us + query_us
-
-
-def crash_recovery_time_s(
-    *, logical_gib: float, chunk_kib: float, footer_read_mib_s: float = 2800.0
-) -> float:
-    """Crash recovery ~ footer reads of all sealed segments (Exp#5): 20 bytes
-    of metadata per 4 KiB block, plus a fixed mount cost."""
-    meta_mib = logical_gib * 1024.0 * (20.0 / 4096.0)
-    return 1.05 + meta_mib / footer_read_mib_s * 60.0  # calibrated to ~1.5s/100GiB
-
-
-def full_drive_recovery_time_s(*, logical_gib: float, k: int, chunk_kib: float) -> float:
-    """Full-drive rebuild ~ read k survivors + write 1/(k+1) of logical space.
-    Calibrated to 81.3 s / 100 GiB at 4 KiB chunks, 18-24% faster for larger
-    chunks (Exp#5)."""
-    base = 81.3 * (logical_gib / 100.0)
-    speedup = {4: 1.0, 8: 0.80, 16: 0.77}.get(int(chunk_kib), 0.77)
-    return base * speedup

@@ -14,7 +14,7 @@ The :class:`MetricsSampler` is an engine actor in the mold of the
 pipeline's self-re-arming flush tick: every ``interval_us`` of *virtual*
 time it runs its collector (a plain callable that reads simulator state
 into the registry) and appends one row to ``series`` -- the time-series
-JSON exported next to the ``BENCH_*`` rows.  It re-arms only while the
+JSON that ``to_json`` exports.  It re-arms only while the
 pipeline/service reports outstanding work, so an idle engine schedules no
 events and a run's event count stays bounded.  Sampling is observe-only:
 collectors read state, never book device time, so the virtual timeline is

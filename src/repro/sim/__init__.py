@@ -7,8 +7,8 @@ Layers (see DESIGN.md §8):
   perfmodel-sampled service times over ``SimZnsDrive``;
 * :mod:`repro.sim.workload` -- MSR-style trace parsing + synthetic and
   multi-tenant generators;
-* :mod:`repro.sim.stats`    -- per-request latency recording, percentiles,
-  BENCH_*.json export.
+* :mod:`repro.sim.stats`    -- per-request latency recording and
+  percentiles.
 
 The timed request pipeline itself lives in :mod:`repro.core.handlers`
 (``HandlerPipeline`` with an engine attached); this package holds the

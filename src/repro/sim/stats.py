@@ -4,14 +4,11 @@
 submit and completion virtual times, and an optional per-stage breakdown
 (buffer wait, device queueing, device service, post-processing) -- and
 reduces them to the distribution figures the paper reports: p50/p95/p99/p999,
-mean, max.  ``to_bench_rows`` emits ``(name, us, derived)`` tuples in the
-exact shape ``benchmarks.run`` prints and serializes, so timed scenarios
-drop into the ``BENCH_*.json`` perf-trajectory format unchanged.
+mean, max.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
 from collections import defaultdict
 from typing import Optional
 
@@ -164,24 +161,3 @@ class LatencyRecorder:
             },
         }
         return out
-
-    def to_bench_rows(self, prefix: str) -> list[tuple[str, float, str]]:
-        """(name, us_per_call, derived) rows, BENCH_*.json-compatible."""
-        rows = []
-        for op, tag in (("W", "write"), ("R", "read")):
-            p = self.percentiles(op=op)
-            if p.get("n"):
-                rows.append((
-                    f"{prefix}/{tag}_p50", p["p50"],
-                    f"p99={p['p99']:.1f}us_p999={p['p999']:.1f}us_n={p['n']}",
-                ))
-        return rows
-
-    def to_json(self, path: str, prefix: str) -> None:
-        out = {
-            name: {"us_per_call": round(us, 2), "derived": derived}
-            for name, us, derived in self.to_bench_rows(prefix)
-        }
-        with open(path, "w") as f:
-            json.dump(out, f, indent=2, sort_keys=True)
-            f.write("\n")

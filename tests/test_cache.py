@@ -3,8 +3,9 @@ zone-granular eviction, and -- the load-bearing property -- bit-identity of
 cached vs uncached reads across every RAID level through overwrites,
 degraded reads, GC relocation, and full-drive rebuild.  Also covers the
 L2P mapping-block cache, the mapping-staging refcount regression, the GC
-reserved-zone escrow, and the timed fast path (cache hits at cache-device
-latency, dispatcher bypass)."""
+reserved-zone escrow, the timed fast path (cache hits at cache-device
+latency, dispatcher bypass), and the read tail the cache cuts on skewed
+healthy streams and on a degraded array."""
 import numpy as np
 import pytest
 
@@ -376,6 +377,44 @@ def test_dispatcher_bypasses_cache_hits():
     assert r_hit.latency_us < r_miss.latency_us
     # bit-identity through the service path
     assert np.array_equal(r_hit.result, pipe.array.read(10, 8))
+
+
+def _healthy_stream_percentiles(kind, burst_factor, *, cache):
+    from repro.checkpoint.zapraid_ckpt import CheckpointConfig
+    from repro.core.handlers import HandlerPipeline
+    from repro.service.scenario import _precondition_region
+    from repro.sim import TenantSpec
+    from repro.sim.workload import synthetic
+
+    logical = 2048
+    cfg = CheckpointConfig(zone_cap_blocks=2048, n_zones=32)
+    pipe = HandlerPipeline.build_timed(cfg.zap_cfg(logical), cfg.zns_cfg(),
+                                       seed=7, flush_interval_us=200.0)
+    tier = None
+    if cache:
+        tier = ZnsCacheTier(CacheConfig(n_zones=8, zone_cap_blocks=32,
+                                        block_bytes=cfg.block_bytes), logical)
+        pipe.attach_cache(tier)
+    _precondition_region(pipe, 0, logical, seed=8)
+    rec = pipe.replay(synthetic(
+        TenantSpec(name="c", kind=kind, n_ops=300, rate_iops=40_000,
+                   read_frac=1.0, burst_factor=burst_factor, seed=9),
+        logical,
+    ))
+    return rec.percentiles(op="R"), tier.stats.hit_rate() if tier else 0.0
+
+
+@pytest.mark.parametrize("kind,burst_factor",
+                         [("zipf", 1.0), ("hotspot", 1.0), ("hotspot", 3.0)])
+def test_skewed_healthy_stream_hits_cache_and_cuts_tail(kind, burst_factor):
+    """On a healthy array a skewed read stream hits the cache, and the
+    same seeded stream reads faster with the cache than without it, at
+    the median and at p99 (virtual time)."""
+    warm, hit_rate = _healthy_stream_percentiles(kind, burst_factor, cache=True)
+    bare, _ = _healthy_stream_percentiles(kind, burst_factor, cache=False)
+    assert hit_rate > 0.0
+    assert warm["p50"] < bare["p50"], (warm, bare)
+    assert warm["p99"] < bare["p99"], (warm, bare)
 
 
 def test_degraded_read_cache_scenario_warm_beats_cold():

@@ -17,8 +17,12 @@ groups back onto the full drive set.  The tests here pin:
 * the :mod:`repro.sim.faults` harness injects failures mid-write, mid-GC
   and mid-checkpoint-save on the timed pipeline, service tier up
   throughout, and the post-rebuild state replays the no-failure oracle;
+* an open-loop write load on the timed pipeline acks in full while
+  degraded and after the paced rebuild has re-widened every narrow segment;
 * the manual-GC escrow floor keeps one restage destination zone.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -411,6 +415,55 @@ def test_checkpoint_saves_commit_through_failure_and_rebuild():
 
 
 # ------------------------------------------------- escrow floor (manual GC)
+
+
+def _narrow_segments(arr):
+    return [sid for sid, rec in arr.segments.items()
+            if len(rec.info.drive_ids) < arr.cfg.n_drives]
+
+
+def test_timed_degraded_write_load_acks_and_rebuild_rewidens():
+    """One drive fails before an open-loop overwrite load on the timed
+    pipeline.  Every write still acks, on survivor-width stripe groups;
+    the paced replace-and-rebuild books device time and re-widens every
+    narrow segment; the same load then acks in full at full width."""
+    from repro.sim import LatencyRecorder, TenantSpec, multi_tenant
+
+    cfg = ZapRaidConfig(scheme="raid5", n_drives=4, group_size=8,
+                        chunk_blocks=1, logical_blocks=256,
+                        gc_free_segments_low=1)
+    zns = ZnsConfig(n_zones=16, zone_cap_blocks=64, block_bytes=BB)
+    pipe = HandlerPipeline.build_timed(cfg, zns, seed=7,
+                                       flush_interval_us=200.0)
+    rng = np.random.default_rng(7)
+    pipe.precondition(
+        (lba, rng.integers(0, 256, (1, BB), dtype=np.uint8))
+        for lba in range(256)
+    )
+    load = multi_tenant([
+        TenantSpec(name="writer", kind="uniform", n_ops=300,
+                   rate_iops=50_000, read_frac=0.0, seed=71),
+    ], logical_blocks=256)
+
+    def shifted():  # arrivals re-based onto the engine's current clock
+        return [dataclasses.replace(r, t_us=r.t_us + pipe.engine.now)
+                for r in load]
+
+    pipe.array.fail_drive(1)
+    degraded = pipe.replay(shifted())
+    assert degraded.percentiles(op="W")["n"] == len(load)
+    assert _narrow_segments(pipe.array)
+
+    pipe.schedule_rebuild(1, at=pipe.engine.now + 10.0, interval_us=20.0)
+    pipe.drain()
+    assert degraded.notes.get("rebuild_device_us", 0.0) > 0.0
+    assert not pipe.array.drives[1].failed
+    assert _narrow_segments(pipe.array) == []
+
+    pipe.recorder = LatencyRecorder()
+    rebuilt = pipe.replay(shifted())
+    assert rebuilt.percentiles(op="W")["n"] == len(load)
+    assert _narrow_segments(pipe.array) == []
 
 
 def test_manual_gc_keeps_one_restage_destination_zone():

@@ -1,9 +1,10 @@
 """Device-resident zero-copy datapath: bit-identity with the legacy path.
 
-The arena-staged, donated-encode, double-buffered group datapath (PR 4) must
-leave *exactly* the media, OOB, write pointers, L2P and validity state the
+The arena-staged, donated-encode group datapath must leave
+*exactly* the media, OOB, write pointers, L2P and validity state the
 per-block/per-stripe legacy path produces -- across schemes, for healthy
-reads, degraded reads on every surviving-role set, rebuild, and GC.  The
+reads, degraded reads on every surviving-role set, rebuild, and GC.  A
+group is on the media when the write that filled it returns.  The
 vectorized L2P batch ops are property-tested against the scalar reference.
 See DESIGN.md §9.
 """
@@ -13,17 +14,16 @@ import pytest
 from _hyp import given, settings, st
 from repro.core.array import ZapRaidConfig, ZapRAIDArray
 from repro.core.l2p import NO_PBA, L2PTable, pack_pba, pack_pba_many, unpack_pba
-from repro.core.zns import ZnsConfig
+from repro.core.zns import DeviceCrashed, ZnsConfig
 
 BB = 256
 SCHEMES = [("raid4", 4), ("raid5", 4), ("raid6", 5), ("raid01", 4)]
 
 
-def _mk(batched, scheme="raid5", n_drives=4, overlap=True, **kw):
+def _mk(batched, scheme="raid5", n_drives=4, **kw):
     cfg = ZapRaidConfig(scheme=scheme, n_drives=n_drives, group_size=8,
                         chunk_blocks=1, logical_blocks=256,
-                        gc_free_segments_low=1, batched=batched,
-                        overlap=overlap, **kw)
+                        gc_free_segments_low=1, batched=batched, **kw)
     zns = ZnsConfig(n_zones=12, zone_cap_blocks=64, block_bytes=BB)
     return ZapRAIDArray(cfg, zns)
 
@@ -76,38 +76,51 @@ def test_partial_group_flush_identical(scheme, n_drives):
     assert a1.stats.padded_blocks == a0.stats.padded_blocks
 
 
-def test_overlap_invisible():
-    """Double-buffered commits change nothing observable on the media."""
-    a1 = _mk(True, overlap=True)
-    a0 = _mk(True, overlap=False)
-    _workload(a1, seed=11)
-    _workload(a0, seed=11)
-    _assert_media_equal(a1, a0)
-
-
-def test_overlap_defers_and_syncs_on_read():
-    """A filled group stays pending until a sync point; reads force it."""
-    arr = _mk(True, overlap=True)
+def test_filled_group_is_on_media_when_write_returns():
+    """A write that fills a stripe group commits it before returning: the
+    L2P maps every block, each member zone's write pointer has moved past
+    the group, and nothing is left in volatile staging."""
+    arr = _mk(True)
     rng = np.random.default_rng(5)
     blk = rng.integers(0, 256, (3 * 8, BB), dtype=np.uint8)  # k*G: one group
     arr.write(0, blk)
-    assert arr._pending_group is not None  # group full, commit deferred
-    got = arr.read(0, 8)  # sync point: read-your-writes
-    assert arr._pending_group is None
-    assert np.array_equal(got, blk[:8])
+    assert not arr.has_staged()
+    assert all(arr.l2p.get(lba) != NO_PBA for lba in range(3 * 8))
+    (ost,) = arr.open_segments.values()
+    info = ost.info
+    assert info.stripes_written == 8
+    for d, zone in zip(info.drive_ids, info.zone_ids):
+        assert int(arr.drives[d].wp[zone]) == info.data_start() + 8
+    assert np.array_equal(arr.read(0, 3 * 8), blk)
 
 
-def test_arm_crash_lands_pending_group_first():
-    """arm_crash must not let the budget bite a pre-arming deferred group."""
-    arr = _mk(True, overlap=True)
-    rng = np.random.default_rng(6)
-    blk = rng.integers(0, 256, (3 * 8, BB), dtype=np.uint8)
+def test_partial_stripe_stays_staged_until_flush():
+    """Blocks short of a full stripe are volatile until ``flush()`` pads
+    and commits them."""
+    arr = _mk(True)
+    blk = np.random.default_rng(8).integers(0, 256, (2, BB), dtype=np.uint8)
     arr.write(0, blk)
-    assert arr._pending_group is not None
-    arr.arm_crash(0)  # sync happens before the budget arms
-    assert arr._pending_group is None
+    assert arr.has_staged()
+    assert arr.l2p.get(0) == NO_PBA
+    arr.flush()
+    assert not arr.has_staged()
+    assert arr.l2p.get(0) != NO_PBA
+    assert np.array_equal(arr.read(0, 2), blk)
+
+
+def test_arm_crash_after_completed_group_keeps_it_readable():
+    """A group committed before ``arm_crash(0)`` is untouched by the
+    budget; the write that fills the next group hits it, in the per-command
+    commit loop, and the first group still reads back after disarming."""
+    arr = _mk(True)
+    rng = np.random.default_rng(6)
+    first = rng.integers(0, 256, (3 * 8, BB), dtype=np.uint8)
+    arr.write(0, first)
+    arr.arm_crash(0)
+    with pytest.raises(DeviceCrashed):
+        arr.write(3 * 8, rng.integers(0, 256, (3 * 8, BB), dtype=np.uint8))
     arr.disarm_crash()
-    assert np.array_equal(arr.read(0, 8), blk[:8])
+    assert np.array_equal(arr.read(0, 3 * 8), first)
 
 
 # ------------------------------------------------------ read-path identity

@@ -329,45 +329,64 @@ def test_rebuild_scalar_open_segment_path():
         assert np.array_equal(d1.wp, d0.wp)
 
 
-def test_timed_gc_actor_paces_background_cleaning():
-    """The rate-limited GC actor cleans proactively on the virtual timeline:
-    it books device time, runs at its watermark, and foreground write p99
-    under GC pressure stays at or below the inline-GC-burst baseline."""
+def _gc_pressure_pipe():
+    """A timed raid5 array aged to its GC watermark (900 writes over 360
+    LBAs on 7 zones)."""
     from repro.core.handlers import HandlerPipeline
-    from repro.sim import TenantSpec, multi_tenant
 
     cfg = ZapRaidConfig(scheme="raid5", n_drives=4, group_size=8,
                         chunk_blocks=1, logical_blocks=360,
                         gc_free_segments_low=1)
     zns = ZnsConfig(n_zones=7, zone_cap_blocks=64, block_bytes=BB)
+    rng = np.random.default_rng(11)
+    pipe = HandlerPipeline.build_timed(cfg, zns, seed=11)
+    pipe.precondition(
+        (i % 360, rng.integers(0, 256, (1, BB), dtype=np.uint8))
+        for i in range(900)
+    )
+    return pipe
 
-    def make_pipe():
-        rng = np.random.default_rng(11)
-        pipe = HandlerPipeline.build_timed(cfg, zns, seed=11)
-        pipe.precondition(
-            (i % 360, rng.integers(0, 256, (1, BB), dtype=np.uint8))
-            for i in range(900)
-        )
-        return pipe
 
-    load = multi_tenant([
-        TenantSpec(name="writer", kind="seq", n_ops=400, rate_iops=50_000,
-                   seed=41),
-        TenantSpec(name="reader", kind="uniform", n_ops=200,
+def _gc_pressure_load(n_writes, n_reads):
+    from repro.sim import TenantSpec, multi_tenant
+
+    return multi_tenant([
+        TenantSpec(name="writer", kind="seq", n_ops=n_writes,
+                   rate_iops=50_000, seed=41),
+        TenantSpec(name="reader", kind="uniform", n_ops=n_reads,
                    rate_iops=20_000, read_frac=1.0, seed=42),
     ], logical_blocks=360)
 
-    pipe = make_pipe()
-    inline = pipe.replay(load)
+
+def test_timed_gc_actor_paces_background_cleaning():
+    """The rate-limited GC actor cleans proactively on the virtual timeline:
+    it books device time, runs at its watermark, and foreground write p99
+    under GC pressure stays at or below the inline-GC-burst baseline."""
+    load = _gc_pressure_load(400, 200)
+    inline = _gc_pressure_pipe().replay(load)
     p_inline = inline.percentiles(op="W")["p99"]
 
-    pipe = make_pipe()
+    pipe = _gc_pressure_pipe()
     pipe.schedule_gc(at=5.0, interval_us=300.0, n_ticks=100)
     actor = pipe.replay(load)
     assert actor.note_counts.get("gc_device_us", 0) > 0  # the actor ran
     assert actor.notes.get("gc_device_us", 0.0) > 0.0    # and booked I/O
     p_actor = actor.percentiles(op="W")["p99"]
     assert p_actor <= p_inline * 1.05  # proactive pacing never worse
+
+
+def test_paced_gc_cuts_write_p99_below_inline_bursts():
+    """Under longer churn the paced GC actor keeps foreground write p99
+    strictly below what inline GC bursts cost on the same load."""
+    load = _gc_pressure_load(500, 300)
+    inline = _gc_pressure_pipe().replay(load)
+    pipe = _gc_pressure_pipe()
+    pipe.schedule_gc(at=5.0, interval_us=300.0, n_ticks=200)
+    paced = pipe.replay(load)
+    assert paced.notes.get("gc_device_us", 0.0) > 0.0
+    p_inline = inline.percentiles(op="W")["p99"]
+    p_paced = paced.percentiles(op="W")["p99"]
+    assert p_paced < p_inline, (p_paced, p_inline)
 
 
 def test_timed_paced_rebuild_routes_reads_and_drains():

@@ -8,8 +8,10 @@ Covers:
 * bit-identity: the same workload through the service vs direct pipeline
   calls leaves identical media, OOB, and read-back;
 * QoS: strict-priority isolation of a latency tenant under an aggressor
-  (p99 separation vs FIFO), EDF ordering within a class, admission
-  rejection at the queue cap, token-bucket shaping;
+  and of serving reads under checkpoint saves (p99 separation vs FIFO),
+  EDF ordering within a class, admission rejection at the queue cap,
+  token-bucket shaping;
+* closed-loop read IOPS that grow with queue depth and saturate;
 * closed-loop driver: the window bounds outstanding requests;
 * the drained-queue flush fix: a lone service write completes from
   ``engine.run()`` alone via self-re-arming timeout-flush ticks;
@@ -167,6 +169,30 @@ def test_qos_isolates_latency_tenant_from_aggressor():
     p99_fifo = _victim_p99("fifo")
     p99_qos = _victim_p99("qos")
     assert p99_qos * 2.0 <= p99_fifo
+
+
+def test_checkpoint_under_serving_qos_beats_fifo():
+    """Checkpoint saves streaming beside serving reads: QoS keeps the
+    serving read p99 at least 2x below FIFO's, and both policies restore
+    the checkpoint bit-identical."""
+    from repro.service.scenario import checkpoint_under_serving
+
+    qos = checkpoint_under_serving(policy="qos")
+    fifo = checkpoint_under_serving(policy="fifo")
+    assert qos["restore_ok"] and fifo["restore_ok"]
+    assert qos["serve_p99_us"] * 2.0 <= fifo["serve_p99_us"], (qos, fifo)
+
+
+def test_read_qd_sweep_iops_grow_with_depth_and_saturate():
+    """Closed-loop reads: virtual IOPS rise with queue depth, and going
+    from QD 16 to 32 buys less than going from 1 to 4, once the drives'
+    channels fill."""
+    from repro.service.scenario import read_qd_sweep
+
+    rows = read_qd_sweep(qds=(1, 4, 16, 32), n_ops=96)
+    iops = [r["virtual_iops"] for r in rows]
+    assert iops == sorted(iops) and len(set(iops)) == len(iops), iops
+    assert iops[3] / iops[2] < iops[1] / iops[0], iops
 
 
 def test_edf_orders_within_priority_class():
