@@ -87,6 +87,8 @@ class TimedDrive(SimZnsDrive):
         # this drive's track.  None (the default) costs one attribute test.
         self.tracer = None
         self._trace_track = f"drive{drive_id}"
+        # mean Zone Append service time: n_blocks -> [_, qd 1, .., append_qd]
+        self._append_us: dict[int, list[float]] = {}
         self.reset_timing()
 
     def reset_timing(self) -> None:
@@ -101,26 +103,23 @@ class TimedDrive(SimZnsDrive):
 
     # -- booking arithmetic -------------------------------------------------
 
-    def _jitter(self) -> float:
-        return float(np.exp(self.jitter_rng.normal(0.0, self.service.jitter_sigma)))
-
-    def _grab_channel(self, floor: float) -> float:
-        """Earliest start >= floor with a free channel; caller books the end."""
-        i = int(np.argmin(self.channels))
-        return max(floor, self.channels[i])
-
-    def _book_channel(self, t_done: float) -> None:
-        i = int(np.argmin(self.channels))
-        self.channels[i] = t_done
+    def _jitters(self, count: int) -> list[float]:
+        """``count`` multiplicative jitters, one draw for all: the same
+        stream, and the same bits, as ``count`` scalar draws."""
+        z = self.jitter_rng.normal(0.0, self.service.jitter_sigma, size=count)
+        return np.exp(z).tolist()
 
     @spanned("media", "book")
     def book_zone_write(self, zone: int, n_blocks: int, floor: float) -> float:
         """Book one Zone Write command; returns its completion time."""
-        start = self._grab_channel(max(floor, float(self.t_zone_free[zone])))
-        svc = self.service.zone_write_us(n_blocks) * self._jitter()
+        ch = self.channels
+        m = min(ch)
+        i = ch.index(m)   # the channel that frees first, the lowest among ties
+        start = max(floor, float(self.t_zone_free[zone]), m)
+        svc = self.service.zone_write_us(n_blocks) * self._jitters(1)[0]
         done = start + svc
         self.t_zone_free[zone] = done
-        self._book_channel(done)
+        ch[i] = done
         self.busy_us += svc
         self.engine.touch_io(done)
         if self.tracer is not None:
@@ -129,30 +128,65 @@ class TimedDrive(SimZnsDrive):
         return done
 
     def book_append(self, zone: int, n_blocks: int, floor: float) -> float:
-        """Book one Zone Append command; returns its completion time.
+        """Book one Zone Append command; returns its completion time."""
+        return self._book_appends(zone, n_blocks, floor, 1)[0]
+
+    def _book_appends(self, zone: int, n_blocks: int, floor: float,
+                      count: int, spans: Optional[list] = None) -> list[float]:
+        """Book ``count`` Zone Append commands to ``zone``, in order; returns
+        their completion times.
 
         At most ``append_qd`` appends are in flight per zone: when the slots
-        are full the command waits for the earliest one to retire.  The
+        are full a command waits for the earliest one to retire.  The
         sampled service time depends on how many siblings are still in
-        flight at start (the intra-zone-parallelism curve)."""
-        slots = self.za_slots.setdefault(zone, [])
-        start = self._grab_channel(floor)
-        busy = sorted(s for s in slots if s > start)
-        if len(busy) >= self.service.append_qd:
-            start = busy[len(busy) - self.service.append_qd]
-            busy = [s for s in busy if s > start]
-        qd_now = len(busy) + 1
-        svc = self.service.zone_append_us(n_blocks, qd_now) * self._jitter()
-        done = start + svc
-        busy.append(done)
-        self.za_slots[zone] = busy[-self.service.append_qd:]
-        self._book_channel(done)
-        self.busy_us += svc
-        self.engine.touch_io(done)
-        if self.tracer is not None:
+        flight at start (the intra-zone-parallelism curve).  Each command
+        takes the channel that frees first.  The result is bit for bit
+        that of ``count`` bookings of one command each.
+
+        With a tracer attached each command's span is emitted at the end,
+        in order; where ``spans`` is given, ``(start, done, qd)`` is
+        appended to it instead, for the caller to emit."""
+        qd = self.service.append_qd
+        means = self._append_us.get(n_blocks)
+        if means is None:
+            means = self._append_us[n_blocks] = [0.0] + [
+                self.service.zone_append_us(n_blocks, q) for q in range(1, qd + 1)]
+        rec = spans
+        if rec is None and self.tracer is not None:
+            rec = []
+        ch = self.channels
+        slots = self.za_slots.get(zone, [])
+        busy_us = self.busy_us
+        out = []
+        for j in self._jitters(count):
+            m = min(ch)
+            i = ch.index(m)
+            start = max(floor, m)
+            busy = sorted([s for s in slots if s > start])
+            if len(busy) >= qd:
+                start = busy[len(busy) - qd]
+                busy = [s for s in busy if s > start]
+            qd_now = len(busy) + 1
+            svc = means[qd_now] * j
+            done = start + svc
+            busy.append(done)
+            slots = busy[-qd:]
+            ch[i] = done
+            busy_us += svc
+            out.append(done)
+            if rec is not None:
+                rec.append((start, done, qd_now))
+        self.za_slots[zone] = slots
+        self.busy_us = busy_us
+        self.engine.touch_io(max(out))
+        if rec is not spans:
+            self._emit_appends(zone, n_blocks, rec)
+        return out
+
+    def _emit_appends(self, zone: int, n_blocks: int, rec) -> None:
+        for start, done, qd_now in rec:
             self.tracer.span(self._trace_track, "zone_append", start, done,
                              zone=zone, n_blocks=n_blocks, qd=qd_now)
-        return done
 
     @spanned("media", "book")
     def book_read(self, n_blocks: int, floor: float) -> float:
@@ -165,13 +199,17 @@ class TimedDrive(SimZnsDrive):
         fan out across the free channels like a real scatter-read."""
         max_b = max(1, self.service.read_cmd_max_blocks)
         done = floor
+        ch = self.channels
+        full_us = self.service.read_us(max_b)
         remaining = n_blocks
-        while remaining > 0:
+        for j in self._jitters(max(0, -(-n_blocks // max_b))):
             nb = min(remaining, max_b)
-            start = self._grab_channel(floor)
-            svc = self.service.read_us(nb) * self._jitter()
+            m = min(ch)
+            i = ch.index(m)
+            start = max(floor, m)
+            svc = (full_us if nb == max_b else self.service.read_us(nb)) * j
             t = start + svc
-            self._book_channel(t)
+            ch[i] = t
             self.busy_us += svc
             done = max(done, t)
             remaining -= nb
@@ -180,10 +218,6 @@ class TimedDrive(SimZnsDrive):
                                  n_blocks=nb)
         self.engine.touch_io(done)
         return done
-
-    def plan_completion(self, zone: int, t_done: float) -> None:
-        """Queue a pre-planned append completion time (see plan_group_appends)."""
-        self._planned.setdefault(zone, deque()).append(t_done)
 
     def clear_planned(self) -> None:
         """Drop leftover pre-planned times (an aborted group never consumed
@@ -202,31 +236,33 @@ class TimedDrive(SimZnsDrive):
 
     def zone_append_commit(self, zone: int, blocks, oobs, crcs=None) -> int:
         off = super().zone_append_commit(zone, blocks, oobs, crcs)
-        with host_span("media", "book"):
-            planned = self._planned.get(zone)
-            if planned:
+        planned = self._planned.get(zone)
+        if planned:
+            with host_span("media", "book"):
                 done = planned.popleft()
                 self.engine.touch_io(done)
-            else:
+        else:
+            with host_span("media", "book", op="book_append", shapes=((1,),)):
                 done = self.book_append(zone, blocks.shape[0], self.engine.now)
-            self.chunk_done[(zone, off)] = done
+        self.chunk_done[(zone, off)] = done
         return off
 
     def zone_append_commit_many(self, zone: int, chunks, oobs, crcs=None) -> np.ndarray:
         offs = super().zone_append_commit_many(zone, chunks, oobs, crcs)
-        with host_span("media", "book"):
-            planned = self._planned.get(zone)
-            c = chunks.shape[1]
-            for off in offs:
-                # the per-zone planned queue is in completion-time order,
-                # which is exactly the per-zone issue order of the group
-                # committer
-                if planned:
-                    done = planned.popleft()
-                    self.engine.touch_io(done)
-                else:
-                    done = self.book_append(zone, c, self.engine.now)
-                self.chunk_done[(zone, int(off))] = done
+        n = offs.shape[0]
+        # the per-zone planned queue is in completion-time order, which is
+        # exactly the per-zone issue order of the group committer
+        planned = self._planned.get(zone)
+        k = min(len(planned), n) if planned else 0
+        meta = {} if k == n else {"op": "book_append", "shapes": ((n - k,),)}
+        with host_span("media", "book", **meta):
+            done = [planned.popleft() for _ in range(k)]
+            if k:
+                self.engine.touch_io(max(done))
+            if k < n:
+                done += self._book_appends(zone, chunks.shape[1],
+                                           self.engine.now, n - k)
+            self.chunk_done.update(zip([(zone, o) for o in offs.tolist()], done))
         return offs
 
     def read(self, zone: int, offset: int, n_blocks: int):
@@ -326,7 +362,6 @@ def make_timed_drives(
     ]
 
 
-@spanned("media", "book")
 def plan_group_appends(
     drives: list[TimedDrive],
     zone_ids: tuple[int, ...],
@@ -345,16 +380,40 @@ def plan_group_appends(
     ``zone_append_commit`` calls (issued in the returned order) attribute
     the right time to the right chunk.
 
+    A drive's bookings depend on that drive's state alone, so each drive
+    books its commands in one call, in submission order; the times are
+    those of booking the commands one by one in submission order, and so
+    are the tracer's spans, which are emitted in that order.
+
     Returns ``(issue_order, group_done_time)``.
     """
-    for d in {d for _, d in ops}:
-        drives[d].clear_planned()  # stale entries from a crash-aborted group
-    done = []
-    for idx, (_, d) in enumerate(ops):
-        t = drives[d].book_append(zone_ids[d], chunk_blocks, floor)
-        done.append((t, idx))
-    done.sort()
-    for t, idx in done:
-        _, d = ops[idx]
-        drives[d].plan_completion(zone_ids[d], t)
-    return [idx for _, idx in done], done[-1][0]
+    with host_span("media", "book", op="plan_group_appends",
+                   shapes=((len(ops),),)):
+        by_drive: dict[int, list[int]] = {}
+        for idx, (_, d) in enumerate(ops):
+            by_drive.setdefault(d, []).append(idx)
+        done = [None] * len(ops)
+        spans = {}
+        for d, idxs in by_drive.items():
+            drive = drives[d]
+            drive.clear_planned()  # stale entries from a crash-aborted group
+            rec = None
+            if drive.tracer is not None:
+                rec = spans[d] = []
+            times = drive._book_appends(zone_ids[d], chunk_blocks, floor,
+                                        len(idxs), rec)
+            for idx, t in zip(idxs, times):
+                done[idx] = (t, idx)
+        if spans:   # emitted as the commands were submitted
+            pending = {d: iter(rec) for d, rec in spans.items()}
+            for _, d in ops:
+                if d in pending:
+                    drives[d]._emit_appends(zone_ids[d], chunk_blocks,
+                                            [next(pending[d])])
+        done.sort()
+        queues = {d: drives[d]._planned.setdefault(zone_ids[d], deque())
+                  for d in by_drive}
+        for t, idx in done:
+            queues[ops[idx][1]].append(t)
+        return [idx for _, idx in done], done[-1][0]
+

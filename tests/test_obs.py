@@ -321,6 +321,68 @@ def test_qd_sweep_rows_identical_with_obs():
     assert read_qd_sweep(obs=False, **kw) == read_qd_sweep(obs=True, **kw)
 
 
+# ------------------------------------------ media booking dispatch counter
+
+
+def test_group_booking_counts_one_dispatch_per_group(monkeypatch):
+    """With host spans on, the planner's ``media:book`` span is a dispatch
+    keyed ``plan_group_appends`` with shapes ``((G*n,),)``: one per group
+    committed, carrying its G*n commands; in a healthy write workload no
+    append is booked outside a group."""
+    from repro.core.array import ZapRAIDArray
+    pipe = _timed_pipe(logical_blocks=96)
+    _precondition(pipe, 96)
+    commands = []
+    commit = ZapRAIDArray._commit_built_group
+
+    def counted(self, grp):
+        commands.append(len(grp["seqs"]) * grp["ost"].info.n_drives)
+        return commit(self, grp)
+
+    monkeypatch.setattr(ZapRAIDArray, "_commit_built_group", counted)
+    rec = HostSpans(annotate=False).install()
+    try:
+        _workload(pipe, rounds=2)
+    finally:
+        rec.uninstall()
+    dispatches = rec.snapshot()["dispatches"]
+    plans = {shapes: k for (op, shapes), k in dispatches.items()
+             if op == "plan_group_appends"}
+    assert sum(plans.values()) == len(commands) > 0
+    assert sum(k * shapes[0][0] for shapes, k in plans.items()) == sum(commands)
+    assert not [op for op, _ in dispatches if op == "book_append"]
+
+
+def test_unplanned_appends_count_as_book_append():
+    """An append with no planned time books itself under the ``book_append``
+    key, one shape entry per call with its command count; planned commits
+    add none."""
+    from repro.core.zns import OOB_DTYPE
+    from repro.sim import Engine, ServiceModel, TimedDrive
+    from repro.sim.device import plan_group_appends
+    eng = Engine()
+    d = TimedDrive(ZnsConfig(n_zones=2, zone_cap_blocks=64, block_bytes=BB), 0,
+                   engine=eng, service=ServiceModel(block_bytes=BB))
+    chunks = np.zeros((3, 1, BB), np.uint8)
+    oobs = np.zeros((3, 1), OOB_DTYPE)
+    rec = HostSpans(annotate=False).install()
+    try:
+        d.zone_append_commit(0, chunks[0], oobs[0])
+        d.zone_append_commit_many(0, chunks, oobs)
+        plan_group_appends([d], (0,), [(s, 0) for s in range(3)], 1, eng.now)
+        d.zone_append_commit_many(0, chunks, oobs)
+    finally:
+        rec.uninstall()
+    snap = rec.snapshot()
+    booked = {key: k for key, k in snap["dispatches"].items()
+              if not key[0].startswith("crc32c")}
+    assert booked == {("book_append", ((1,),)): 1,
+                                  ("book_append", ((3,),)): 1,
+                                  ("plan_group_appends", ((3,),)): 1}
+    assert snap["spans"]["media:book"]["count"] == 4
+    assert len(d.chunk_done) == 7
+
+
 # ------------------------------------------------------ escrow auto-size
 
 

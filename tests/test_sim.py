@@ -13,6 +13,8 @@ Covers the PR-3 subsystem end to end:
   permutation path across RAID schemes, including after crash recovery;
 * degraded reads under load showing tail inflation.
 """
+from collections import deque
+
 import numpy as np
 import pytest
 
@@ -20,12 +22,14 @@ from repro.core.array import ZapRaidConfig, ZapRAIDArray
 from repro.core.handlers import HandlerPipeline
 from repro.core.recovery import recover_array
 from repro.core.zns import (
+    OOB_DTYPE,
     CrashBudget,
     SimZnsDrive,
     TooManyOpenZones,
     ZnsConfig,
     ZoneState,
 )
+from repro.obs import Tracer
 from repro.sim import (
     Engine,
     Request,
@@ -424,3 +428,247 @@ def test_rebuild_under_load_books_device_time():
     # post-rebuild the array reads clean without degraded decodes
     got = pipe.array.read(0, 1)
     assert got.shape == (1, 256)
+
+
+# ------------------------- batched booking: bit-identity with per command
+#
+# The reference below is the per-command booking the drive model used before
+# it booked a group's commands per drive in one pass: one scalar jitter draw,
+# two ``np.argmin`` channel picks and one service-model call per command.
+# The batched booking must reproduce its virtual times bit for bit.
+
+
+def _ref_jitter(d):
+    return float(np.exp(d.jitter_rng.normal(0.0, d.service.jitter_sigma)))
+
+
+def _ref_grab_channel(d, floor):
+    i = int(np.argmin(d.channels))
+    return max(floor, d.channels[i])
+
+
+def _ref_book_channel(d, t_done):
+    i = int(np.argmin(d.channels))
+    d.channels[i] = t_done
+
+
+def _ref_book_zone_write(d, zone, n_blocks, floor):
+    start = _ref_grab_channel(d, max(floor, float(d.t_zone_free[zone])))
+    svc = d.service.zone_write_us(n_blocks) * _ref_jitter(d)
+    done = start + svc
+    d.t_zone_free[zone] = done
+    _ref_book_channel(d, done)
+    d.busy_us += svc
+    d.engine.touch_io(done)
+    if d.tracer is not None:
+        d.tracer.span(d._trace_track, "zone_write", start, done,
+                      zone=zone, n_blocks=n_blocks)
+    return done
+
+
+def _ref_book_append(d, zone, n_blocks, floor):
+    slots = d.za_slots.setdefault(zone, [])
+    start = _ref_grab_channel(d, floor)
+    busy = sorted(s for s in slots if s > start)
+    if len(busy) >= d.service.append_qd:
+        start = busy[len(busy) - d.service.append_qd]
+        busy = [s for s in busy if s > start]
+    qd_now = len(busy) + 1
+    svc = d.service.zone_append_us(n_blocks, qd_now) * _ref_jitter(d)
+    done = start + svc
+    busy.append(done)
+    d.za_slots[zone] = busy[-d.service.append_qd:]
+    _ref_book_channel(d, done)
+    d.busy_us += svc
+    d.engine.touch_io(done)
+    if d.tracer is not None:
+        d.tracer.span(d._trace_track, "zone_append", start, done,
+                      zone=zone, n_blocks=n_blocks, qd=qd_now)
+    return done
+
+
+def _ref_book_read(d, n_blocks, floor):
+    max_b = max(1, d.service.read_cmd_max_blocks)
+    done = floor
+    remaining = n_blocks
+    while remaining > 0:
+        nb = min(remaining, max_b)
+        start = _ref_grab_channel(d, floor)
+        svc = d.service.read_us(nb) * _ref_jitter(d)
+        t = start + svc
+        _ref_book_channel(d, t)
+        d.busy_us += svc
+        done = max(done, t)
+        remaining -= nb
+        if d.tracer is not None:
+            d.tracer.span(d._trace_track, "read", start, t, n_blocks=nb)
+    d.engine.touch_io(done)
+    return done
+
+
+def _ref_plan_group_appends(drives, zone_ids, ops, chunk_blocks, floor):
+    for d in {d for _, d in ops}:
+        drives[d].clear_planned()
+    done = []
+    for idx, (_, d) in enumerate(ops):
+        t = _ref_book_append(drives[d], zone_ids[d], chunk_blocks, floor)
+        done.append((t, idx))
+    done.sort()
+    for t, idx in done:
+        _, d = ops[idx]
+        drives[d]._planned.setdefault(zone_ids[d], deque()).append(t)
+    return [idx for _, idx in done], done[-1][0]
+
+
+def _ref_zone_append_commit(d, zone, blocks, oobs, crcs=None):
+    off = SimZnsDrive.zone_append_commit(d, zone, blocks, oobs, crcs)
+    planned = d._planned.get(zone)
+    if planned:
+        done = planned.popleft()
+        d.engine.touch_io(done)
+    else:
+        done = _ref_book_append(d, zone, blocks.shape[0], d.engine.now)
+    d.chunk_done[(zone, off)] = done
+    return off
+
+
+def _ref_zone_append_commit_many(d, zone, chunks, oobs, crcs=None):
+    offs = SimZnsDrive.zone_append_commit_many(d, zone, chunks, oobs, crcs)
+    planned = d._planned.get(zone)
+    for off in offs:
+        if planned:
+            done = planned.popleft()
+            d.engine.touch_io(done)
+        else:
+            done = _ref_book_append(d, zone, chunks.shape[1], d.engine.now)
+        d.chunk_done[(zone, int(off))] = done
+    return offs
+
+
+def _use_reference_booking(monkeypatch):
+    """Route every booking of the drive model through the reference."""
+    import repro.sim.device as device
+    monkeypatch.setattr(device, "plan_group_appends", _ref_plan_group_appends)
+    for name, fn in (("book_zone_write", _ref_book_zone_write),
+                     ("book_append", _ref_book_append),
+                     ("book_read", _ref_book_read),
+                     ("zone_append_commit", _ref_zone_append_commit),
+                     ("zone_append_commit_many", _ref_zone_append_commit_many)):
+        monkeypatch.setattr(TimedDrive, name, fn)
+
+
+def _drive_set(seed, sigma, traced, n=4):
+    eng = Engine()
+    cfg = ZnsConfig(n_zones=4, zone_cap_blocks=4096, block_bytes=16)
+    kw = {} if sigma is None else {"jitter_sigma": sigma}
+    service = ServiceModel(block_bytes=4096, **kw)
+    drives = [TimedDrive(cfg, i, engine=eng, service=service, seed=seed + 101 * i)
+              for i in range(n)]
+    tracer = Tracer(eng) if traced else None
+    for d in drives:
+        d.tracer = tracer
+    return eng, drives, tracer
+
+
+def _drive_state(d):
+    return (list(d.channels), {z: list(s) for z, s in d.za_slots.items()},
+            {z: list(q) for z, q in d._planned.items()}, d.busy_us,
+            d.t_zone_free.tolist(), d.jitter_rng.bit_generator.state)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["plain", "traced"])
+@pytest.mark.parametrize("sigma", [0.0, None], ids=["sigma0", "sigma_default"])
+@pytest.mark.parametrize("chunk_blocks", [1, 4])
+@pytest.mark.parametrize("members", [(0, 1, 2, 3), (0, 2, 3)],
+                         ids=["width4", "narrow3"])
+@pytest.mark.parametrize("seed", [0, 2**31 + 17])
+def test_batched_booking_bit_identical_to_per_command(
+        seed, members, chunk_blocks, sigma, traced):
+    """``plan_group_appends`` (one pass per drive), ``book_read`` (one jitter
+    draw per call) and ``book_zone_write`` give the per-command reference's
+    issue order, group time, planned queues, channels, qd slots, busy time,
+    RNG state, I/O watermark and tracer spans, over consecutive groups with
+    floors before and after earlier completions."""
+    from repro.sim.device import plan_group_appends
+    ref_eng, ref_drives, ref_tr = _drive_set(seed, sigma, traced)
+    new_eng, new_drives, new_tr = _drive_set(seed, sigma, traced)
+    rng = np.random.default_rng(seed)
+    n = len(members)
+    zone_ids = tuple(int(z) for z in rng.integers(0, 4, n))
+    group_done = 0.0
+    for g in range(4):
+        s_count = int(rng.integers(1, 40))
+        ops = [(s, d) for s in range(s_count) for d in range(n)]
+        if g == 3:   # any submission order, not only stripe by stripe
+            ops = [ops[i] for i in rng.permutation(len(ops))]
+        # a floor before the last group's completion, then after it
+        floor = group_done * (0.5 if g % 2 else 1.0) + 3.0 * g
+        ref = _ref_plan_group_appends([ref_drives[m] for m in members],
+                                      zone_ids, ops, chunk_blocks, floor)
+        new = plan_group_appends([new_drives[m] for m in members],
+                                 zone_ids, ops, chunk_blocks, floor)
+        assert new == ref
+        group_done = ref[1]
+        for m in members:
+            nb = int(rng.integers(1, 40))
+            assert (new_drives[m].book_read(nb, floor + 1.0)
+                    == _ref_book_read(ref_drives[m], nb, floor + 1.0))
+        z = int(rng.integers(0, 4))
+        assert (new_drives[members[0]].book_zone_write(z, chunk_blocks, floor)
+                == _ref_book_zone_write(ref_drives[members[0]], z,
+                                        chunk_blocks, floor))
+        for d_ref, d_new in zip(ref_drives, new_drives):
+            assert _drive_state(d_new) == _drive_state(d_ref)
+        assert new_eng.io_watermark == ref_eng.io_watermark
+    # unplanned appends fall back to booking at the engine's clock
+    chunks = np.zeros((7, chunk_blocks, 16), np.uint8)
+    oobs = np.zeros((7, chunk_blocks), OOB_DTYPE)
+    for eng in (ref_eng, new_eng):
+        eng.now = group_done
+    for d_ref, d_new in zip(ref_drives, new_drives):
+        d_ref.clear_planned()
+        d_new.clear_planned()
+        assert ([d_new.book_append(1, chunk_blocks, group_done) for _ in range(5)]
+                == [_ref_book_append(d_ref, 1, chunk_blocks, group_done)
+                    for _ in range(5)])
+        assert (d_new.zone_append_commit(2, chunks[0], oobs[0])
+                == _ref_zone_append_commit(d_ref, 2, chunks[0], oobs[0]))
+        d_new.zone_append_commit_many(2, chunks, oobs)
+        _ref_zone_append_commit_many(d_ref, 2, chunks, oobs)
+        assert d_new.chunk_done == d_ref.chunk_done
+        assert _drive_state(d_new) == _drive_state(d_ref)
+    assert new_eng.io_watermark == ref_eng.io_watermark
+    if traced:   # in the order emitted, and as exported
+        assert new_tr.events == ref_tr.events
+        assert new_tr.to_trace_events() == ref_tr.to_trace_events()
+
+
+@pytest.mark.parametrize("batched", [True, False], ids=["batched", "legacy"])
+def test_timed_pipeline_books_as_per_command_reference(monkeypatch, batched):
+    """A traced timed pipeline (group commits through the bulk and the
+    per-command committer, reads, a drive failure and a rebuild) gives the
+    same virtual clock, completion times, busy time and exported trace
+    events as with every booking routed through the per-command
+    reference."""
+    def run():
+        pipe = _timed_pipe(seed=5, batched=batched)
+        tracer = pipe.attach_obs()
+        rng = np.random.default_rng(11)
+        t = 0.0
+        for lba, data in _write_workload(rng, 80, 128):
+            t += 6.0
+            pipe.submit_write(lba, data, at=t)
+        for i in range(40):
+            pipe.submit_read((i * 7) % 120, 3, at=t + 10.0 * i)
+        pipe.schedule_drive_failure(1, t + 450.0)
+        pipe.schedule_rebuild(1, t + 600.0, interval_us=40.0)
+        pipe.drain()
+        return (pipe.engine.now, pipe.engine.io_watermark,
+                [(d.busy_us, dict(d.chunk_done)) for d in pipe.array.drives],
+                tracer.events, tracer.to_trace_events())
+
+    new = run()
+    with monkeypatch.context() as mp:
+        _use_reference_booking(mp)
+        ref = run()
+    assert new == ref
